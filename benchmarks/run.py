@@ -16,6 +16,8 @@ import time
 
 
 def main(argv=None):
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (bench_cloud, bench_long_generation,
                             bench_mobile, bench_profiles, bench_roofline,
                             bench_serving, bench_tco)

@@ -122,7 +122,7 @@ def main():
         outputs[kv] = {r.rid: r.output for r in eng.finished}
         print(f"\n[{kv}] {s['requests']} requests, {s['tokens']} tokens, "
               f"{s['tokens_per_s']:.1f} tok/s, mean TTFT "
-              f"{s['mean_ttft_s']*1e3:.0f} ms (CPU interpret-mode numbers)")
+              f"{s['mean_ttft_s']*1e3:.0f} ms")
         print(f"  single-dispatch decode: {s['decode_dispatches']} "
               f"dispatches over {s['decode_steps']} steps "
               f"({s['dispatches_per_step']:.2f}/step), "

@@ -46,6 +46,7 @@ from dataclasses import dataclass, field, replace
 from functools import partial
 
 import jax
+import jax.extend.core as jex_core
 import numpy as np
 
 # primitive classification ---------------------------------------------------
@@ -84,8 +85,8 @@ REDUCE_PRIMS = {"reduce_sum", "reduce_max", "reduce_min", "reduce_prod",
                 "cummax", "cumlogsumexp", "cumprod"}
 DATA_PRIMS = {"gather", "scatter", "scatter-add", "scatter_add",
               "dynamic_slice", "dynamic_update_slice", "concatenate",
-              "pad", "slice", "sort", "top_k", "take", "rng_bit_generator",
-              "select_and_scatter_add"}
+              "pad", "slice", "split", "sort", "top_k", "take",
+              "rng_bit_generator", "select_and_scatter_add"}
 CALL_PRIMS = {"pjit", "closed_call", "custom_jvp_call", "custom_vjp_call",
               "custom_vjp_call_jaxpr", "core_call", "remat_call", "remat",
               "checkpoint", "named_call", "custom_transpose_call",
@@ -189,12 +190,12 @@ def _index_map_grid_deps(index_map_jaxpr, n_grid: int) -> list:
     it also handles maps that dereference scalar-prefetch operands
     (paged block tables: ``tab[b, w]`` depends on grid axes b and w
     *through* the table)."""
-    jaxpr = getattr(index_map_jaxpr, "jaxpr", index_map_jaxpr)
-    needed = {v for v in jaxpr.outvars if isinstance(v, jax.core.Var)}
+    jaxpr = index_map_jaxpr.jaxpr
+    needed = {v for v in jaxpr.outvars if isinstance(v, jex_core.Var)}
     for eqn in reversed(jaxpr.eqns):
         if any(v in needed for v in eqn.outvars):
             needed.update(v for v in eqn.invars
-                          if isinstance(v, jax.core.Var))
+                          if isinstance(v, jex_core.Var))
     return [jaxpr.invars[i] in needed
             for i in range(min(n_grid, len(jaxpr.invars)))]
 
@@ -206,10 +207,8 @@ def _block_mapping_bytes(bm, grid) -> float:
     invariant to — e.g. the split-KV decode kernel streams each KV tile
     exactly once while its q / output blocks are fetched once per
     (batch, head), not per KV block."""
-    shape = [int(d) for d in bm.block_shape
-             if isinstance(d, (int, np.integer))]
-    elems = int(np.prod(shape, dtype=np.int64)) if shape else 1
-    itemsize = np.dtype(bm.array_shape_dtype.dtype).itemsize
+    elems = int(np.prod(bm.transformed_block_aval.shape, dtype=np.int64))
+    itemsize = np.dtype(bm.array_aval.dtype).itemsize
     deps = _index_map_grid_deps(bm.index_map_jaxpr, len(grid))
     fetches = 1
     for axis, dep in enumerate(deps):
@@ -227,10 +226,9 @@ def _pallas_record(eqn) -> OpRecord:
     record only when the grid is dynamic (not statically priceable)."""
     gm = eqn.params["grid_mapping"]
     grid = tuple(gm.grid)
-    name = getattr(eqn.params.get("name_and_src_info"), "name", "") \
-        or "pallas"
-    if getattr(gm, "num_dynamic_grid_bounds", 0) or not all(
-            isinstance(d, (int, np.integer)) for d in grid):
+    name = (eqn.params["name"]
+            or eqn.params["jaxpr"].debug_info.func_name)
+    if not all(isinstance(d, (int, np.integer)) for d in grid):
         return OpRecord(
             "other", "pallas_call", 0.0,
             sum(_aval_bytes(v.aval) for v in eqn.invars),
@@ -243,7 +241,7 @@ def _pallas_record(eqn) -> OpRecord:
     rows = max((o.rows for o in mm), default=0)
     # memory traffic: scalar-prefetch operands land whole (SMEM), block
     # operands stream per the BlockSpec fetch model above
-    n_pref = int(getattr(gm, "num_index_operands", 0))
+    n_pref = int(gm.num_index_operands)
     in_b = sum(_aval_bytes(v.aval) for v in eqn.invars[:n_pref])
     n_in = int(gm.num_inputs)
     for bm in gm.block_mappings[:n_in]:

@@ -10,9 +10,9 @@ the TPU analogue of the paper's bank-parallel split — and all G query
 heads of one KV head share each streamed tile (the GQA amplification
 that PIM-AI's capacity argument is about).
 
-Grid: (B, Hkv, num_s_blocks); the cache lengths arrive as a per-row
-(B,) scalar-prefetch vector so each batch row masks its own valid KV
-span — the fully-ragged continuous-batching case where every serving
+Grid: (B, Hkv / heads-per-block, num_s_blocks); the cache lengths
+arrive as a per-row (B,) scalar-prefetch vector so each batch row masks
+its own valid KV span — the fully-ragged continuous-batching case where every serving
 slot sits at a different absolute position — without the host slicing
 the cache or splitting the batch into position groups. A scalar
 ``cache_len`` is accepted too (broadcast to all rows).
@@ -38,11 +38,26 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
+
 NEG_INF = -1e30
+
+
+def _head_block(hkv: int) -> int:
+    """KV heads per grid step. A block's last two dims are (heads, Dh):
+    Mosaic needs the heads dim to be a multiple of 8 or the whole head
+    axis (Dh is always whole), so 8 heads ride together when they
+    divide, and small head counts take the full axis."""
+    return 8 if hkv % 8 == 0 else hkv
 
 
 def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
             scale, block_s):
+    # q/o (1, G, hb, Dh); k/v (1, block_s, hb, Dh); m/l (G, hb, 1) and
+    # acc (G, hb, Dh) hold the online-softmax state of every head in
+    # the block. Scores are lane reductions over Dh (VPU) rather than
+    # per-head matmuls: decode is bandwidth-bound, and this keeps the
+    # streamed (positions, heads, Dh) tile in its HBM layout.
     sb = pl.program_id(2)
     ns = pl.num_programs(2)
     cache_len = len_ref[pl.program_id(0)]  # this row's valid KV span
@@ -53,40 +68,44 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    s_pos = sb * block_s + jax.lax.iota(jnp.int32, block_s)
-    any_valid = sb * block_s < cache_len
-
-    @pl.when(any_valid)
+    @pl.when(sb * block_s < cache_len)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale       # (G, dh)
-        k = k_ref[0, :, 0]                                # (bs, dh)
-        s = jax.lax.dot_general(
-            q, k.astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)           # (G, bs)
-        s = jnp.where((s_pos < cache_len)[None, :], s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev[:, 0], jnp.max(s, axis=-1))[:, None]
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, -1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p, v_ref[0, :, 0].astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)           # (G, dh)
-        acc_ref[...] = acc_ref[...] * alpha + pv
-        m_ref[...] = m_new
+        k = k_ref[0].astype(jnp.float32)                  # (bs, hb, dh)
+        v = v_ref[0].astype(jnp.float32)
+        pos = sb * block_s + jax.lax.broadcasted_iota(
+            jnp.int32, (block_s, k.shape[1], 1), 0)
+        valid = pos < cache_len                           # (bs, hb, 1)
+        for g in range(q_ref.shape[1]):
+            q = q_ref[0, g].astype(jnp.float32) * scale   # (hb, dh)
+            s = jnp.sum(k * q[None], axis=-1, keepdims=True)
+            s = jnp.where(valid, s, NEG_INF)              # (bs, hb, 1)
+            m_prev = m_ref[g]                             # (hb, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new[None])
+            l_ref[g] = l_ref[g] * alpha + jnp.sum(p, axis=0)
+            acc_ref[g] = acc_ref[g] * alpha + jnp.sum(p * v, axis=0)
+            m_ref[g] = m_new
 
     @pl.when(sb == ns - 1)
     def _emit():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
-def decode_attention_bhgd(q, k_cache, v_cache, cache_len, *, block_s=512,
-                          interpret=True):
+def _scratch(g, hb, dh):
+    return [pltpu.VMEM((g, hb, 1), jnp.float32),
+            pltpu.VMEM((g, hb, 1), jnp.float32),
+            pltpu.VMEM((g, hb, dh), jnp.float32)]
+
+
+def decode_attention_bhgd(q, k_cache, v_cache, cache_len, *, block_s=256,
+                          interpret=None):
     """q (B, Hkv, G, Dh); caches (B, S, Hkv, Dh); cache_len scalar or
     per-row (B,) int32 valid-KV lengths. Returns (B, Hkv, G, Dh)."""
     b, hkv, g, dh = q.shape
     s = k_cache.shape[1]
+    hb = _head_block(hkv)
     block_s = min(block_s, max(8, s))
     ns = math.ceil(s / block_s)
     s_p = ns * block_s
@@ -96,32 +115,25 @@ def decode_attention_bhgd(q, k_cache, v_cache, cache_len, *, block_s=512,
 
     kernel = functools.partial(_kernel, scale=1.0 / math.sqrt(dh),
                                block_s=block_s)
+    qo_spec = pl.BlockSpec((1, g, hb, dh),
+                           lambda bi, h, si, *_: (bi, 0, h, 0))
+    kv_spec = pl.BlockSpec((1, block_s, hb, dh),
+                           lambda bi, h, si, *_: (bi, si, h, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(b, hkv, ns),
-        in_specs=[
-            pl.BlockSpec((1, 1, g, dh), lambda bi, h, si, *_: (bi, h, 0, 0)),
-            pl.BlockSpec((1, block_s, 1, dh),
-                         lambda bi, h, si, *_: (bi, si, h, 0)),
-            pl.BlockSpec((1, block_s, 1, dh),
-                         lambda bi, h, si, *_: (bi, si, h, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, dh),
-                               lambda bi, h, si, *_: (bi, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, dh), jnp.float32),
-        ],
+        grid=(b, hkv // hb, ns),
+        in_specs=[qo_spec, kv_spec, kv_spec],
+        out_specs=qo_spec,
+        scratch_shapes=_scratch(g, hb, dh),
     )
     lens = jnp.broadcast_to(
         jnp.asarray(cache_len, jnp.int32).reshape(-1), (b,))
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, g, dh), q.dtype),
-        interpret=interpret,
-    )(lens, q, k_cache, v_cache)
-    return out
+        out_shape=jax.ShapeDtypeStruct((b, g, hkv, dh), q.dtype),
+        interpret=interpret_mode(interpret), name="decode_attention",
+    )(lens, jnp.swapaxes(q, 1, 2), k_cache, v_cache)
+    return jnp.swapaxes(out, 1, 2)
 
 
 def _paged_kernel(len_ref, tab_ref, *rest, **kw):
@@ -132,7 +144,7 @@ def _paged_kernel(len_ref, tab_ref, *rest, **kw):
 
 
 def decode_attention_paged_bhgd(q, k_pool, v_pool, block_tables, cache_len,
-                                *, interpret=True):
+                                *, interpret=None):
     """Paged split-KV flash decode.
 
     q (B, Hkv, G, Dh); ``k_pool``/``v_pool`` (NB, bs, Hkv, Dh) shared
@@ -146,34 +158,27 @@ def decode_attention_paged_bhgd(q, k_pool, v_pool, block_tables, cache_len,
     b, hkv, g, dh = q.shape
     nb, bs, _, _ = k_pool.shape
     w = block_tables.shape[1]
+    hb = _head_block(hkv)
     kernel = functools.partial(_paged_kernel, scale=1.0 / math.sqrt(dh),
                                block_s=bs)
+    qo_spec = pl.BlockSpec((1, g, hb, dh),
+                           lambda bi, h, wi, *_: (bi, 0, h, 0))
+    kv_spec = pl.BlockSpec((1, bs, hb, dh),
+                           lambda bi, h, wi, lens, tab:
+                           (jnp.minimum(tab[bi, wi], nb - 1), 0, h, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, hkv, w),
-        in_specs=[
-            pl.BlockSpec((1, 1, g, dh),
-                         lambda bi, h, wi, *_: (bi, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, dh),
-                         lambda bi, h, wi, lens, tab:
-                         (jnp.minimum(tab[bi, wi], nb - 1), 0, h, 0)),
-            pl.BlockSpec((1, bs, 1, dh),
-                         lambda bi, h, wi, lens, tab:
-                         (jnp.minimum(tab[bi, wi], nb - 1), 0, h, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, dh),
-                               lambda bi, h, wi, *_: (bi, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, dh), jnp.float32),
-        ],
+        grid=(b, hkv // hb, w),
+        in_specs=[qo_spec, kv_spec, kv_spec],
+        out_specs=qo_spec,
+        scratch_shapes=_scratch(g, hb, dh),
     )
     lens = jnp.broadcast_to(
         jnp.asarray(cache_len, jnp.int32).reshape(-1), (b,))
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, g, dh), q.dtype),
-        interpret=interpret,
-    )(lens, jnp.asarray(block_tables, jnp.int32), q, k_pool, v_pool)
-    return out
+        out_shape=jax.ShapeDtypeStruct((b, g, hkv, dh), q.dtype),
+        interpret=interpret_mode(interpret), name="paged_decode_attention",
+    )(lens, jnp.asarray(block_tables, jnp.int32), jnp.swapaxes(q, 1, 2),
+      k_pool, v_pool)
+    return jnp.swapaxes(out, 1, 2)

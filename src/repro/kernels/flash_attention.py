@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
+
 NEG_INF = -1e30
 
 
@@ -94,7 +96,9 @@ def _hist_kernel(len_ref, q_ref, kh_ref, vh_ref, ks_ref, vs_ref, o_ref,
     the unwritten history tail, while within-chunk masking is plain
     causality in chunk-relative coordinates — independent of the
     (dynamic) history length, so the block skip for the self region
-    stays static."""
+    stays static. The two phases are separate ``pl.when`` regions, each
+    reading its own operands: Mosaic cannot lower a select between
+    whole operand tiles."""
     qb = pl.program_id(1)
     kb = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -106,27 +110,14 @@ def _hist_kernel(len_ref, q_ref, kh_ref, vh_ref, ks_ref, vs_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    in_self = kb >= nk_hist
-    rel_q = qb * block_q + jax.lax.iota(jnp.int32, block_q)
-    rel_k = (kb - nk_hist) * block_k + jax.lax.iota(jnp.int32, block_k)
-    hist_pos = kb * block_k + jax.lax.iota(jnp.int32, block_k)
-    # history block: any position < hist_len; self block: causal reach
-    visible = jnp.where(in_self,
-                        (kb - nk_hist) * block_k <= qb * block_q
-                        + block_q - 1,
-                        kb * block_k < hist_len)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
 
-    @pl.when(visible)
-    def _compute():
+    def accumulate(k_ref, v_ref, ok):
         q = q_ref[0].astype(jnp.float32) * scale            # (bq, dh)
-        k = jnp.where(in_self, ks_ref[0], kh_ref[0])        # (bk, dh)
-        v = jnp.where(in_self, vs_ref[0], vh_ref[0])
         s = jax.lax.dot_general(
-            q, k.astype(jnp.float32), (((1,), (1,)), ((), ())),
+            q, k_ref[0].astype(jnp.float32), (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)             # (bq, bk)
-        ok = jnp.where(in_self,
-                       rel_k[None, :] <= rel_q[:, None],
-                       (hist_pos < hist_len)[None, :])
         s = jnp.where(ok, s, NEG_INF)
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev[:, 0], jnp.max(s, axis=-1))[:, None]
@@ -134,10 +125,23 @@ def _hist_kernel(len_ref, q_ref, kh_ref, vh_ref, ks_ref, vs_ref, o_ref,
         p = jnp.exp(s - m_new)
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, -1, keepdims=True)
         pv = jax.lax.dot_general(
-            p, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
+            p, v_ref[0].astype(jnp.float32), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)             # (bq, dh)
         acc_ref[...] = acc_ref[...] * alpha + pv
         m_ref[...] = m_new
+
+    # history block: any position < hist_len
+    @pl.when(jnp.logical_and(kb < nk_hist, kb * block_k < hist_len))
+    def _history():
+        accumulate(kh_ref, vh_ref, kb * block_k + cols < hist_len)
+
+    # self block: causal reach in chunk-relative coordinates
+    ks0 = (kb - nk_hist) * block_k
+    q0 = qb * block_q
+
+    @pl.when(jnp.logical_and(kb >= nk_hist, ks0 <= q0 + block_q - 1))
+    def _self():
+        accumulate(ks_ref, vs_ref, ks0 + cols <= q0 + rows)
 
     @pl.when(kb == nk - 1)
     def _emit():
@@ -146,7 +150,7 @@ def _hist_kernel(len_ref, q_ref, kh_ref, vh_ref, ks_ref, vs_ref, o_ref,
 
 
 def flash_attention_hist_bhsd(q, k_hist, v_hist, k_self, v_self, hist_len,
-                              *, block_q=128, block_k=128, interpret=True):
+                              *, block_q=128, block_k=128, interpret=None):
     """Prefill-over-cache: q (BH, S, Dh) at absolute positions
     ``hist_len + 0..S-1`` attends ``k_hist``/``v_hist`` (BH, C, Dh)
     masked to the first ``hist_len`` rows (scalar or per-row (BH,)
@@ -222,13 +226,13 @@ def flash_attention_hist_bhsd(q, k_hist, v_hist, k_self, v_self, hist_len,
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bh, sq_p, dh), q.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret), name="prefill_attention",
     )(lens, q, k_hist, v_hist, k_self, v_self)
     return out[:, :sq]
 
 
 def flash_attention_bhsd(q, k, v, *, causal=True, window=None, q_offset=0,
-                         block_q=128, block_k=128, interpret=True):
+                         block_q=128, block_k=128, interpret=None):
     """q (BH, Sq, Dh); k, v (BH, Skv, Dh) — heads pre-expanded/merged."""
     bh, sq, dh = q.shape
     sk = k.shape[1]
@@ -262,6 +266,6 @@ def flash_attention_bhsd(q, k, v, *, causal=True, window=None, q_offset=0,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, dh), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret), name="flash_attention",
     )(q, k, v)
     return out[:, :sq]

@@ -1,8 +1,8 @@
 """Jitted public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (the kernel body runs in Python
-via the Pallas interpreter — bit-accurate against the BlockSpec tiling)
-and False on real TPU backends.
+Each kernel runs compiled on a TPU backend and in the Pallas interpreter
+(bit-accurate against the BlockSpec tiling) everywhere else — decided
+once, by :func:`repro.kernels.interpret_mode`.
 """
 from __future__ import annotations
 
@@ -16,17 +16,6 @@ from repro.kernels import flash_attention as _fa
 from repro.kernels import quant_gemv as _qg
 from repro.kernels import rmsnorm as _rn
 from repro.kernels.ref import quantize_int4, pack_int4  # noqa: F401
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # noqa: BLE001
-        return False
-
-
-def _interpret() -> bool:
-    return not _on_tpu()
 
 
 @partial(jax.jit, static_argnames=("causal", "window", "q_offset",
@@ -44,12 +33,12 @@ def flash_attention(q, k, v, *, causal=True, window=None, q_offset=0,
     vm = jnp.moveaxis(v, 2, 1).reshape(b * hq, -1, dh)
     o = _fa.flash_attention_bhsd(qm, km, vm, causal=causal, window=window,
                                  q_offset=q_offset, block_q=block_q,
-                                 block_k=block_k, interpret=_interpret())
+                                 block_k=block_k)
     return jnp.moveaxis(o.reshape(b, hq, sq, dh), 1, 2)
 
 
 @partial(jax.jit, static_argnames=("block_s",))
-def decode_attention(q, k_cache, v_cache, cache_len, *, block_s=512):
+def decode_attention(q, k_cache, v_cache, cache_len, *, block_s=256):
     """q (B,1,Hq,Dh); caches (B,S,Hkv,Dh). Split-KV GQA flash decode.
     ``cache_len``: scalar, or per-row (B,) int32 for ragged batches."""
     b, _, hq, dh = q.shape
@@ -57,7 +46,7 @@ def decode_attention(q, k_cache, v_cache, cache_len, *, block_s=512):
     g = hq // hkv
     qg = q.reshape(b, hkv, g, dh)
     o = _dec.decode_attention_bhgd(qg, k_cache, v_cache, cache_len,
-                                   block_s=block_s, interpret=_interpret())
+                                   block_s=block_s)
     return o.reshape(b, 1, hq, dh)
 
 
@@ -85,8 +74,7 @@ def prefill_attention(q, k_hist, v_hist, hist_len, k_self, v_self):
     lens = jnp.broadcast_to(
         jnp.asarray(hist_len, jnp.int32).reshape(-1, 1), (b, hq)
     ).reshape(b * hq)
-    o = _fa.flash_attention_hist_bhsd(qm, khm, vhm, ksm, vsm, lens,
-                                      interpret=_interpret())
+    o = _fa.flash_attention_hist_bhsd(qm, khm, vhm, ksm, vsm, lens)
     return jnp.moveaxis(o.reshape(b, hq, sq, dh), 1, 2)
 
 
@@ -119,18 +107,16 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, cache_len):
     g = hq // hkv
     qg = q.reshape(b, hkv, g, dh)
     o = _dec.decode_attention_paged_bhgd(qg, k_pool, v_pool, block_tables,
-                                         cache_len,
-                                         interpret=_interpret())
+                                         cache_len)
     return o.reshape(b, 1, hq, dh)
 
 
 @partial(jax.jit, static_argnames=("group", "block_n"))
 def quant_gemv(x, w_packed, scales, *, group=128, block_n=256):
     return _qg.quant_gemv(x, w_packed, scales, group=group,
-                          block_n=block_n, interpret=_interpret())
+                          block_n=block_n)
 
 
 @partial(jax.jit, static_argnames=("eps", "block_m"))
 def rmsnorm(x, w, *, eps=1e-6, block_m=8):
-    return _rn.rmsnorm(x, w, eps=eps, block_m=block_m,
-                       interpret=_interpret())
+    return _rn.rmsnorm(x, w, eps=eps, block_m=block_m)

@@ -26,6 +26,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
+
 
 def _kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *, group):
     kb = pl.program_id(1)
@@ -35,16 +37,17 @@ def _kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *, group):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    packed = w_ref[...]                       # (group//2, bn) uint8
-    lo = (packed & 0xF).astype(jnp.int8)
-    hi = (packed >> 4).astype(jnp.int8)
+    packed = w_ref[...].astype(jnp.int32)     # (group//2, bn)
+    lo = packed & 0xF                         # rows 2i of the group
+    hi = (packed >> 4) & 0xF                  # rows 2i+1
     lo = jnp.where(lo >= 8, lo - 16, lo)
     hi = jnp.where(hi >= 8, hi - 16, hi)
-    # interleave rows back: w[2i] = lo[i], w[2i+1] = hi[i]
-    half, bn = packed.shape
-    w = jnp.stack([lo, hi], axis=1).reshape(group, bn)    # (group, bn)
-    w = w.astype(jnp.float32) * s_ref[0].astype(jnp.float32)[None, :]
-    x = x_ref[...].astype(jnp.float32)        # (B, group)
+    # [even rows; odd rows] — a sublane-aligned concat, matched by the
+    # wrapper's per-group [even; odd] reordering of x (an in-register
+    # row interleave does not lower on TPU)
+    w = jnp.concatenate([lo, hi], axis=0).astype(jnp.float32)
+    w = w * s_ref[0].astype(jnp.float32)      # (group, bn) * (1, bn)
+    x = x_ref[...].astype(jnp.float32)        # (B, group), [even; odd]
     acc_ref[...] += jax.lax.dot_general(
         x, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
@@ -54,7 +57,7 @@ def _kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *, group):
 
 
 def quant_gemv(x, w_packed, scales, *, group=128, block_n=256,
-               interpret=True):
+               interpret=None):
     """x (B, K) bf16/f32; w_packed (K//2, N) uint8; scales (K//group, N).
     Returns (B, N) in x.dtype."""
     b, k = x.shape
@@ -76,11 +79,14 @@ def quant_gemv(x, w_packed, scales, *, group=128, block_n=256,
         in_specs=[
             pl.BlockSpec((b, group), lambda ni, ki: (0, ki)),
             pl.BlockSpec((group // 2, block_n), lambda ni, ki: (ki, ni)),
-            pl.BlockSpec((1, block_n), lambda ni, ki: (ki, ni)),
+            # scales ride as (K//group, 1, N): a one-row block over a
+            # unit middle dim satisfies Mosaic's (8, 128) tiling rule
+            pl.BlockSpec((1, 1, block_n), lambda ni, ki: (ki, 0, ni)),
         ],
         out_specs=pl.BlockSpec((b, block_n), lambda ni, ki: (0, ni)),
         out_shape=jax.ShapeDtypeStruct((b, n_p), x.dtype),
         scratch_shapes=[pltpu.VMEM((b, block_n), jnp.float32)],
-        interpret=interpret,
-    )(x, w_packed, scales)
+        interpret=interpret_mode(interpret), name="quant_gemv",
+    )(x.reshape(b, nk, group // 2, 2).swapaxes(2, 3).reshape(b, k),
+      w_packed, scales[:, None, :])
     return out[:, :n]

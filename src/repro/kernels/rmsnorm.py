@@ -13,6 +13,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_mode
+
 
 def _kernel(x_ref, w_ref, o_ref, *, eps):
     x = x_ref[...].astype(jnp.float32)                    # (bm, d)
@@ -22,7 +24,7 @@ def _kernel(x_ref, w_ref, o_ref, *, eps):
                   ).astype(o_ref.dtype)
 
 
-def rmsnorm(x, w, *, eps=1e-6, block_m=8, interpret=True):
+def rmsnorm(x, w, *, eps=1e-6, block_m=8, interpret=None):
     """x (..., d); w (d,). Row-tiled fused RMSNorm."""
     orig_shape = x.shape
     d = x.shape[-1]
@@ -42,6 +44,6 @@ def rmsnorm(x, w, *, eps=1e-6, block_m=8, interpret=True):
         ],
         out_specs=pl.BlockSpec((block_m, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m_p, d), x.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret), name="rmsnorm",
     )(xm, w)
     return out[:m].reshape(orig_shape)

@@ -5,8 +5,11 @@ if __name__ == "__main__":
     # repro.launch.dryrun`, including the --all subprocess driver).
     # Importers (tests, roofline, scripts) bring their own device
     # count — an unconditional set here would clobber e.g. the
-    # 8-device test worlds before their jax import.
+    # 8-device test worlds before their jax import. The placeholders are
+    # CPU devices: pinned explicitly, so neither this process nor the
+    # per-cell children it starts ever reach for an attached TPU.
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+    os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
@@ -80,35 +83,11 @@ def _collective_bytes(hlo_text: str) -> dict:
             "total_bytes": sum(out.values())}
 
 
-def peak_memory_bytes(mem) -> int:
-    """Version-tolerant peak-memory read for ``memory_analysis()``.
-
-    jax has renamed/dropped ``CompiledMemoryStats.peak_memory_in_bytes``
-    across releases; this accepts the stats object OR a serialized
-    record dict (old and new spellings) and falls back to
-    argument+output+temp — the upper bound XLA's peak tracker refines —
-    so fit checks degrade conservatively instead of crashing."""
-    def get(k):
-        v = mem.get(k) if isinstance(mem, dict) else getattr(mem, k, None)
-        return None if v is None else int(v)
-
-    for k in ("peak_memory_in_bytes", "peak_memory_bytes"):
-        v = get(k)
-        if v is not None and v > 0:
-            return v
-    return sum(get(k) or 0 for k in ("argument_size_in_bytes",
-                                     "output_size_in_bytes",
-                                     "temp_size_in_bytes"))
-
-
 def _mem_dict(mem) -> dict:
     keys = ("argument_size_in_bytes", "output_size_in_bytes",
             "temp_size_in_bytes", "generated_code_size_in_bytes",
             "alias_size_in_bytes", "peak_memory_in_bytes")
-    out = {k: int(getattr(mem, k)) for k in keys if hasattr(mem, k)}
-    # keep the record schema stable for roofline across jax versions
-    out["peak_memory_in_bytes"] = peak_memory_bytes(mem)
-    return out
+    return {k: int(getattr(mem, k)) for k in keys}
 
 
 def run_cell(arch: str, shape_name: str, mesh_kind: str,

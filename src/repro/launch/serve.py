@@ -14,11 +14,13 @@ import numpy as np
 
 from repro.checkpoint import CheckpointManager
 from repro.configs import registry
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as MD
 from repro.serving import EngineConfig, ServingEngine
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-0.5b")
     ap.add_argument("--smoke", action="store_true")

@@ -24,14 +24,22 @@ def dtype_of(cfg) -> jnp.dtype:
 # init helpers
 # ---------------------------------------------------------------------------
 
+def _normal(key, shape):
+    """Float32 standard normals. The barrier keeps a jitted init from
+    folding the caller's scale into the sampler's own constants, so
+    jitted and eager inits draw bit-identical weights."""
+    return jax.lax.optimization_barrier(
+        jax.random.normal(key, shape, jnp.float32))
+
+
 def dense_init(key, shape, dtype, fan_in: int | None = None):
     fan = fan_in if fan_in is not None else shape[0]
     scale = 1.0 / math.sqrt(max(1, fan))
-    return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
+    return (_normal(key, shape) * scale).astype(dtype)
 
 
 def embed_init(key, shape, dtype):
-    return (jax.random.normal(key, shape, jnp.float32) * 0.02).astype(dtype)
+    return (_normal(key, shape) * 0.02).astype(dtype)
 
 
 def zeros_init(_key, shape, dtype):

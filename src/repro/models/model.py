@@ -36,7 +36,7 @@ from repro.models import moe as M
 from repro.models import ssm as S
 from repro.models import xlstm as X
 from repro.models.attention import (attention, decode_attention,
-                                    prefill_over_cache)
+                                    gather_kv_blocks, prefill_over_cache)
 from repro.distributed import hints
 
 TRANSFORMER_FAMILIES = ("dense", "moe", "vlm")
@@ -248,7 +248,12 @@ def decoder_block_decode(p, cfg, x, k_cache, v_cache, cache_len, *,
 # init_params — family dispatch
 # ---------------------------------------------------------------------------
 
+@partial(jax.jit, static_argnames="cfg")
 def init_params(key, cfg):
+    """Random parameters for ``cfg``, drawn from ``key``. Jitted, so
+    each leaf is drawn in float32 and cast inside one program: only the
+    ``cfg.dtype`` weights are ever materialised on the device (an eager
+    init holds a float32 transient of the largest stacked leaf)."""
     ks = jax.random.split(key, 8)
     p = {"embed": L.init_embedding(ks[0], cfg),
          "final_norm": L.init_norm(ks[1], cfg)}
@@ -687,7 +692,7 @@ def prefill(params, cfg, batch, capacity, *, attn_impl="chunked",
 
 
 def prefill_chunk(params, cfg, batch, k_hist, v_hist, hist_len, *,
-                  attn_impl="chunked", logit_index=None):
+                  attn_impl="chunked", logit_index=None, block_table=None):
     """Process one prompt chunk against cached history (chunked /
     Sarathi-style prefill). Attention families only (dense/moe/vlm, no
     rolling SWA) — recurrent state cannot resume from a KV view, those
@@ -701,6 +706,9 @@ def prefill_chunk(params, cfg, batch, k_hist, v_hist, hist_len, *,
     paged pool), valid to ``hist_len`` (traced scalar) — chunk *k*
     attends chunks ``0..k-1`` through them. Pad-position KV is garbage
     downstream code masks by length, exactly like bucketed prefill.
+    With ``block_table`` (W,), ``k_hist``/``v_hist`` are the paged pools
+    (L, NB, bs, Hkv, Dh) and each layer gathers the slot's blocks inside
+    the layer scan, so only one layer's history view is ever live.
 
     Returns (logits (B, V) read at ``logit_index`` within the chunk,
     ks, vs (L, B, S, Hkv, Dh)) — the chunk's KV rows, to be spliced at
@@ -716,18 +724,24 @@ def prefill_chunk(params, cfg, batch, k_hist, v_hist, hist_len, *,
     s = x.shape[1]
     positions = jnp.asarray(hist_len, jnp.int32) + jnp.arange(s)
     n_first = len(params.get("first_layers", []))
+
+    def view(layer_hist):
+        if block_table is None:
+            return layer_hist
+        return gather_kv_blocks(layer_hist, block_table[None])
+
     k_news, v_news = [], []
     for i, lp in enumerate(params.get("first_layers", [])):
         x, (k1, v1) = decoder_block_chunk(
-            lp, cfg, x, k_hist[i], v_hist[i], hist_len,
+            lp, cfg, x, view(k_hist[i]), view(v_hist[i]), hist_len,
             positions=positions, attn_impl=attn_impl)
         k_news.append(k1)
         v_news.append(v1)
 
     def body(h, xs):
         lp, kh, vh = xs
-        h, (k1, v1) = decoder_block_chunk(lp, cfg, h, kh, vh, hist_len,
-                                          positions=positions,
+        h, (k1, v1) = decoder_block_chunk(lp, cfg, h, view(kh), view(vh),
+                                          hist_len, positions=positions,
                                           attn_impl=attn_impl)
         return h, (k1, v1)
 
@@ -751,82 +765,117 @@ def prefill_chunk(params, cfg, batch, k_hist, v_hist, hist_len, *,
 # decode step
 # ---------------------------------------------------------------------------
 
-def _write_token_kv(cache_arr, kv, slot, live=None):
-    """Write one decoded token's KV ``kv`` (L|G, B, 1, H, Dh) into
-    ``cache_arr`` (L|G, B, C, H, Dh) at ``slot`` — a scalar, or a per-row
-    (B,) vector for ragged continuous batching. Rows where ``live`` is
-    False keep their previous cache exactly (the write is dropped, no
-    full-cache merge)."""
+def write_window(cache_arr, kv, row, pos, n_valid):
+    """Write one row's S tokens of KV ``kv`` (L|G, 1, S, H, Dh) into row
+    ``row`` of ``cache_arr`` (L|G, B, C, H, Dh) at positions ``pos ..
+    pos+S-1``; tokens at index ``>= n_valid`` and positions past the
+    capacity keep the cache's previous contents exactly.
+
+    A read-modify-write ``dynamic_update_slice`` of the S-position
+    window, never a scatter over (row, position): the TPU keeps the
+    cache with its position axis minor, and a scatter there makes the
+    compiler copy the whole cache into a row-major temporary. The
+    update aliases a donated cache buffer in place."""
     kv = kv.astype(cache_arr.dtype)
-    slot = jnp.asarray(slot, jnp.int32)
-    if slot.ndim == 0:
-        out = jax.lax.dynamic_update_slice(cache_arr, kv, (0, 0, slot, 0, 0))
-        if live is not None:
-            out = jnp.where(live.reshape(1, -1, 1, 1, 1), out, cache_arr)
-        return out
-    b, c = cache_arr.shape[1], cache_arr.shape[2]
-    if live is not None:
-        slot = jnp.where(live, slot, c)  # out-of-range rows are dropped
-    return cache_arr.at[:, jnp.arange(b), slot].set(kv[:, :, 0], mode="drop")
+    s, c = kv.shape[2], cache_arr.shape[2]
+    start = jnp.clip(pos, 0, c - s)        # the window a DUS can land
+    idx = (0, row, start, 0, 0)
+    shift = pos - start                    # > 0 only past the capacity
+    # token t sits at window slot t + shift: shift the tokens in with a
+    # dynamic slice of the zero-padded window (a gather here would pull
+    # the whole cache into a row-major layout, like a scatter)
+    new = jax.lax.dynamic_slice_in_dim(
+        jnp.concatenate([jnp.zeros_like(kv), kv], axis=2), s - shift, s,
+        axis=2)
+    t = jnp.arange(s) - shift              # token index at each slot
+    keep = (t >= 0) & (t < n_valid)
+    old = jax.lax.dynamic_slice(cache_arr, idx, kv.shape)
+    return jax.lax.dynamic_update_slice(
+        cache_arr, jnp.where(keep[None, None, :, None, None], new, old), idx)
 
 
 def _write_tokens_kv(cache_arr, kv, pos, live=None):
-    """Multi-token generalization of :func:`_write_token_kv` for the
-    speculative-verify dispatch: scatter ``kv`` (L|G, B, S, H, Dh) — the
-    KV of S candidate tokens per row — into ``cache_arr``
-    (L|G, B, C, H, Dh) at per-row positions ``pos[b] .. pos[b]+S-1``.
-    Rows where ``live`` is False, and positions past the capacity,
-    drop the write (rejected-candidate KV past the accepted prefix is
-    garbage the per-row length vector masks, exactly like bucketed-
-    prefill pad KV)."""
-    kv = kv.astype(cache_arr.dtype)
-    b, c = cache_arr.shape[1], cache_arr.shape[2]
-    s = kv.shape[2]
-    pos2 = (jnp.asarray(pos, jnp.int32).reshape(-1, 1)
-            + jnp.arange(s)[None, :])                       # (B, S)
-    if live is not None:
-        pos2 = jnp.where(live.reshape(-1, 1), pos2, c)  # dropped below
-    return cache_arr.at[:, jnp.arange(b)[:, None], pos2].set(
-        kv, mode="drop")
-
-
-def _write_token_kv_paged(pool, kv, block_tab, pos, live=None):
-    """Paged analogue of :func:`_write_token_kv`: scatter one decoded
-    token's KV ``kv`` (L, B, 1, H, Dh) into the shared block pool
-    (L, NB, bs, H, Dh) at each row's ``pos`` via its block table
-    (B, W). Rows that are not live, or whose table entry is the
-    sentinel ``NB`` (block never allocated), drop the write."""
-    kv = kv.astype(pool.dtype)
-    nb, bs = pool.shape[1], pool.shape[2]
-    b = kv.shape[1]
+    """Write S new tokens' KV ``kv`` (L|G, B, S, H, Dh) into
+    ``cache_arr`` (L|G, B, C, H, Dh) at per-row positions ``pos[b] ..
+    pos[b]+S-1`` (``pos`` a scalar or a (B,) vector). Rows where
+    ``live`` is False, and positions past the capacity, keep their
+    previous contents (rejected verify candidates and free slots are
+    masked downstream by the per-row length vector). One
+    :func:`write_window` per row."""
+    b, s = cache_arr.shape[1], kv.shape[2]
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
-    w_idx = jnp.minimum(pos // bs, block_tab.shape[1] - 1)
-    blk = jnp.take_along_axis(block_tab, w_idx[:, None], axis=1)[:, 0]
-    if live is not None:
-        blk = jnp.where(live, blk, nb)  # out-of-range rows are dropped
-    return pool.at[:, blk, pos % bs].set(kv[:, :, 0], mode="drop")
+    for i in range(b):
+        n_valid = s if live is None else jnp.where(live[i], s, 0)
+        cache_arr = write_window(cache_arr, kv[:, i:i + 1], i, pos[i],
+                                 n_valid)
+    return cache_arr
+
+
+def write_pool_positions(pool, kv, blk, off):
+    """Write tokens' KV ``kv`` (L, R, S, H, Dh) into the shared block
+    pool (L, NB, bs, H, Dh): token (r, t) lands in block ``blk[r, t]``
+    at row ``off[r, t]``; entries with ``blk >= NB`` (the sentinel)
+    leave the pool unchanged. A loop of read-modify-write updates, one
+    per token, for the same layout reason as :func:`write_window` (the
+    pool keeps its block axis minor)."""
+    nb = pool.shape[1]
+    r, s = kv.shape[1], kv.shape[2]
+    kv = kv.astype(pool.dtype)
+    upd = (pool.shape[0], 1, 1) + pool.shape[3:]
+
+    def body(n, pool):
+        i, t = n // s, n % s
+        b = blk[i, t]
+        new = jax.lax.dynamic_slice(kv, (0, i, t, 0, 0), upd)
+        idx = (0, jnp.minimum(b, nb - 1), off[i, t], 0, 0)
+        old = jax.lax.dynamic_slice(pool, idx, upd)
+        return jax.lax.dynamic_update_slice(
+            pool, jnp.where(b < nb, new, old), idx)
+
+    return jax.lax.fori_loop(0, r * s, body, pool)
+
+
+def write_pool_blocks(pool, rows, blocks):
+    """Dense rows (L, 1, n*bs, H, Dh) -> pool blocks ``blocks`` (n,):
+    block i of the rows lands in pool block ``blocks[i]``; sentinel
+    entries (>= NB) are dropped. A loop of whole-block updates (the
+    pool's block axis is minor on the TPU, where a scatter along it
+    copies the pool)."""
+    nb, bs = pool.shape[1], pool.shape[2]
+    rows = rows.astype(pool.dtype)
+    upd = (pool.shape[0], 1, bs) + pool.shape[3:]
+
+    def body(i, pool):
+        b = blocks[i]
+        new = jax.lax.dynamic_slice_in_dim(rows, i * bs, bs, axis=2)
+        idx = (0, jnp.minimum(b, nb - 1), 0, 0, 0)
+        old = jax.lax.dynamic_slice(pool, idx, upd)
+        return jax.lax.dynamic_update_slice(
+            pool, jnp.where(b < nb, new, old), idx)
+
+    return jax.lax.fori_loop(0, blocks.shape[0], body, pool)
 
 
 def _write_tokens_kv_paged(pool, kv, block_tab, pos, live=None):
-    """Paged analogue of :func:`_write_tokens_kv`: scatter S candidate
-    tokens' KV ``kv`` (L, B, S, H, Dh) into the shared block pool
-    (L, NB, bs, H, Dh) at each row's ``pos[b] + 0..S-1`` via its block
-    table (B, W). Rows that are not live, positions past the table's
-    capacity, and sentinel (never-allocated) table entries all drop the
-    write — a verify window is only backed by real blocks up to the
-    row's commit cap, everything beyond is rejected-candidate garbage."""
-    kv = kv.astype(pool.dtype)
+    """Paged analogue of :func:`_write_tokens_kv`: S tokens' KV ``kv``
+    (L, B, S, H, Dh) land in the shared block pool (L, NB, bs, H, Dh) at
+    each row's ``pos[b] + 0..S-1`` via its block table (B, W). Rows that
+    are not live, positions past the table's capacity, and sentinel
+    (never-allocated) table entries all keep the pool unchanged — a
+    verify window is only backed by real blocks up to the row's commit
+    cap, everything beyond is rejected-candidate garbage."""
     nb, bs = pool.shape[1], pool.shape[2]
     b, s = kv.shape[1], kv.shape[2]
     w = block_tab.shape[1]
-    pos2 = (jnp.asarray(pos, jnp.int32).reshape(-1, 1)
-            + jnp.arange(s)[None, :])                       # (B, S)
-    w_idx = jnp.minimum(pos2 // bs, w - 1)
-    blk = jnp.take_along_axis(block_tab, w_idx, axis=1)     # (B, S)
-    blk = jnp.where(pos2 >= w * bs, nb, blk)  # past capacity: drop
+    pos2 = (jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
+            [:, None] + jnp.arange(s)[None, :])              # (B, S)
+    blk = jnp.take_along_axis(block_tab, jnp.minimum(pos2 // bs, w - 1),
+                              axis=1)                        # (B, S)
+    drop = pos2 >= w * bs
     if live is not None:
-        blk = jnp.where(live.reshape(-1, 1), blk, nb)
-    return pool.at[:, blk, pos2 % bs].set(kv, mode="drop")
+        drop = drop | ~live.reshape(-1, 1)
+    return write_pool_positions(pool, kv, jnp.where(drop, nb, blk),
+                                pos2 % bs)
 
 
 def _merge_rows(new, old, live, axis):
@@ -893,13 +942,13 @@ def decode_step(params, cfg, tokens, cache, *, live=None):
             ks = jnp.concatenate([jnp.stack(k_news), ks], axis=0)
             vs = jnp.concatenate([jnp.stack(v_news), vs], axis=0)
         if btab is None:
-            cache["k"] = _write_token_kv(cache["k"], ks, slot, live)
-            cache["v"] = _write_token_kv(cache["v"], vs, slot, live)
+            cache["k"] = _write_tokens_kv(cache["k"], ks, slot, live)
+            cache["v"] = _write_tokens_kv(cache["v"], vs, slot, live)
         else:
-            cache["k"] = _write_token_kv_paged(cache["k"], ks, btab, n,
-                                               live)
-            cache["v"] = _write_token_kv_paged(cache["v"], vs, btab, n,
-                                               live)
+            cache["k"] = _write_tokens_kv_paged(cache["k"], ks, btab, n,
+                                                live)
+            cache["v"] = _write_tokens_kv_paged(cache["v"], vs, btab, n,
+                                                live)
 
     elif fam == "audio":
         pos = n.reshape(-1, 1) if n.ndim else jnp.full((1, 1), n, jnp.int32)
@@ -914,8 +963,8 @@ def decode_step(params, cfg, tokens, cache, *, live=None):
         x, (ks, vs) = jax.lax.scan(
             body, x, (params["layers"], cache["k"], cache["v"],
                       cache["cross_k"], cache["cross_v"]))
-        cache["k"] = _write_token_kv(cache["k"], ks, n, live)
-        cache["v"] = _write_token_kv(cache["v"], vs, n, live)
+        cache["k"] = _write_tokens_kv(cache["k"], ks, n, live)
+        cache["v"] = _write_tokens_kv(cache["v"], vs, n, live)
 
     elif fam == "ssm":
         def super_body(h, xs):
@@ -968,8 +1017,8 @@ def decode_step(params, cfg, tokens, cache, *, live=None):
                                    axes["ssm"])
         cache["conv"] = _merge_rows(outs["cv"].astype(cache["conv"].dtype),
                                     cache["conv"], live, axes["conv"])
-        cache["k"] = _write_token_kv(cache["k"], outs["k1"], n, live)
-        cache["v"] = _write_token_kv(cache["v"], outs["v1"], n, live)
+        cache["k"] = _write_tokens_kv(cache["k"], outs["k1"], n, live)
+        cache["v"] = _write_tokens_kv(cache["v"], outs["v1"], n, live)
     else:
         raise ValueError(fam)
 
@@ -1027,7 +1076,6 @@ def verify_tokens(params, cfg, tokens, cache, *, live=None,
     def hist_view(kc, vc):
         if btab is None:
             return kc, vc
-        from repro.models.attention import gather_kv_blocks
         return gather_kv_blocks(kc, btab), gather_kv_blocks(vc, btab)
 
     n_first = len(params.get("first_layers", []))

@@ -37,7 +37,6 @@ from __future__ import annotations
 import json
 import os
 
-from repro.launch.dryrun import peak_memory_bytes
 
 # TPU v5e per-chip constants (assignment-specified)
 PEAK_FLOPS = 197e12      # bf16
@@ -92,7 +91,8 @@ def analyze_record(rec: dict) -> dict:
         "model_flops": mf,
         "model_flops_ratio": mf / g_flops if g_flops else 0.0,
         "roofline_frac": (mf / chips / PEAK_FLOPS) / bound_s,
-        "peak_bytes_per_chip": peak_memory_bytes(rec.get("memory") or {}),
+        "peak_bytes_per_chip": int(
+            (rec.get("memory") or {}).get("peak_memory_in_bytes", 0)),
     }
     return out
 

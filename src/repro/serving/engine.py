@@ -109,6 +109,12 @@ from repro.serving.scheduler import PrefillState, make_scheduler
 from repro.serving.telemetry import NULL_TELEMETRY
 
 
+# operand index of the KV cache in the decode/verify closures
+# ``(params, toks, cache, pos, live)``: the engine donates it, so each
+# step's new cache reuses the resident buffer
+CACHE_ARGNUM = 2
+
+
 def build_closures(cfg, capacity: int, *, masked: bool | None = None):
     """The engine's jitted dispatch graphs, as plain functions of
     ``(params, *operands)``, keyed by dispatch kind.
@@ -155,16 +161,12 @@ def build_closures(cfg, capacity: int, *, masked: bool | None = None):
 
     def chunk_paged(params, batch, pool_k, pool_v, table, hist_len,
                     logit_idx):
-        """Paged analogue: the slot's block-table row gathers its
-        pool blocks into the dense history view (PR 2's dense-view
-        gather), garbage blocks masked by ``hist_len``."""
-        nb, bs = pool_k.shape[1], pool_k.shape[2]
-        idx = jnp.clip(table, 0, nb - 1)  # (W,) sentinel -> clamped
-        l, w = pool_k.shape[0], idx.shape[0]
-        kh = pool_k[:, idx].reshape(l, 1, w * bs, *pool_k.shape[3:])
-        vh = pool_v[:, idx].reshape(l, 1, w * bs, *pool_v.shape[3:])
-        return MD.prefill_chunk(params, cfg, batch, kh, vh, hist_len,
-                                logit_index=logit_idx)
+        """Paged analogue: each layer gathers the slot's pool blocks
+        through its block-table row into a dense history view (sentinels
+        clamped, garbage blocks masked by ``hist_len``)."""
+        return MD.prefill_chunk(params, cfg, batch, pool_k, pool_v,
+                                hist_len, logit_index=logit_idx,
+                                block_table=table)
 
     def verify(params, toks, cache, pos, live):
         """One multi-token verify dispatch: every live slot's
@@ -500,12 +502,16 @@ class ServingEngine:
         self._closures = build_closures(cfg, C)
         self._prefill_one = self._jit(
             self._closures["prefill"])  # one compile per bucket
+        # decode/verify donate the cache operand: the new cache aliases
+        # the resident one, so a step never holds two caches at once
         self._decode_ragged = self._jit(
-            self._closures["decode"])  # one compile total
+            self._closures["decode"], donate=CACHE_ARGNUM)  # one compile
         self._verify_ragged = self._jit(
-            self._closures["verify"])  # one compile total
+            self._closures["verify"], donate=CACHE_ARGNUM)  # one compile
         # chunked prefill: slot/hist_len/logit_idx traced -> one compile
-        # per chunk shape (two for vlm: first chunk carries the images)
+        # per chunk shape (two for vlm: first chunk carries the images).
+        # The chunk graphs only read the cache (their KV rows land via
+        # the backend's donating splice), so they donate nothing.
         self._chunk_fns = {
             "contiguous": self._jit(self._closures["chunk_contiguous"]),
             "paged": self._jit(self._closures["chunk_paged"])}
@@ -520,14 +526,15 @@ class ServingEngine:
         if self.scheduler.name == "speculative":
             self._init_draft(draft_params, draft_cfg)
 
-    def _jit(self, fn):
-        """``jax.jit`` a dispatch closure; on a mesh, enter the armed
+    def _jit(self, fn, donate=()):
+        """``jax.jit`` a dispatch closure (``donate``: argnums whose
+        buffers the dispatch consumes); on a mesh, enter the armed
         sharding-hint context around every call. The hints are
         contextvars read at *trace* time, so the first call of each
         shape lowers to the gather-rows tensor-parallel graph and later
         calls hit the compiled cache — still exactly one jitted
         dispatch per step. Outside a mesh this is plain ``jax.jit``."""
-        jitted = jax.jit(fn)
+        jitted = jax.jit(fn, donate_argnums=donate)
         if self.mesh is None:
             return jitted
         mesh = self.mesh
@@ -589,7 +596,8 @@ class ServingEngine:
         self._draft_prefill = self._jit(
             self._draft_closures["prefill"])  # per bucket
         self._draft_decode = self._jit(
-            self._draft_closures["decode"])   # one compile total
+            self._draft_closures["decode"],
+            donate=CACHE_ARGNUM)  # one compile total
 
     def _make_sampler(self):
         """Sampling head over returned logits — outside the model jits,

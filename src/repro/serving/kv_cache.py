@@ -54,6 +54,7 @@ from __future__ import annotations
 import hashlib
 import math
 import warnings
+from functools import partial
 from typing import Protocol, runtime_checkable
 
 import jax
@@ -419,6 +420,74 @@ def _export_span(n_valid: int) -> int:
 
 
 # ---------------------------------------------------------------------------
+# cache jits: each update donates the cache it rewrites, so it aliases
+# the resident buffer in place instead of holding two copies
+# ---------------------------------------------------------------------------
+
+@partial(jax.jit, donate_argnums=0)
+def splice_rows(cache: dict, rows: dict, slot):
+    """Land a batch-1 cache (every batched leaf: KV rows, recurrent or
+    cross state) in row ``slot`` of the dense cache. ``slot`` is traced:
+    one compile total."""
+    axes = MD.cache_batch_axes(cache)
+    return {name: (leaf if axes[name] is None
+                   else jax.lax.dynamic_update_slice_in_dim(
+                       leaf, rows[name].astype(leaf.dtype), slot,
+                       axes[name]))
+            for name, leaf in cache.items()}
+
+
+@partial(jax.jit, donate_argnums=(0, 1))
+def splice_chunk(cache_k, cache_v, rows_k, rows_v, slot, offset, n_valid):
+    """One prefill chunk's rows (L, 1, S, H, Dh) -> positions ``offset ..
+    offset + n_valid - 1`` of row ``slot``; the pad tail (and anything
+    past the capacity) is dropped, never clamped back onto real
+    positions. One compile per chunk shape."""
+    return (MD.write_window(cache_k, rows_k, slot, offset, n_valid),
+            MD.write_window(cache_v, rows_v, slot, offset, n_valid))
+
+
+@partial(jax.jit, donate_argnums=(0, 1))
+def splice_blocks(pool_k, pool_v, rows_k, rows_v, blocks):
+    """Dense rows (L, 1, n*bs, H, Dh) -> pool blocks ``blocks`` (n,):
+    block i of the rows lands in ``blocks[i]``; sentinel entries (>= NB,
+    the pad blocks past a prompt) are dropped. Used at admission (n = W,
+    one compile) and at handoff import (one compile per block count)."""
+    return (MD.write_pool_blocks(pool_k, rows_k, blocks),
+            MD.write_pool_blocks(pool_v, rows_v, blocks))
+
+
+@jax.jit
+def gather_blocks(pool, blocks):
+    """Pool blocks ``blocks`` (n,) of every layer as dense rows
+    (L, 1, n*bs, H, Dh). Gathers layer by layer: one gather along the
+    block axis of all layers at once makes the TPU compiler relayout
+    the whole pool into a temporary. One compile per block count."""
+    rows = jax.lax.map(lambda layer: layer[blocks], pool)
+    return rows.reshape(pool.shape[0], 1, -1, *pool.shape[3:])
+
+
+@partial(jax.jit, donate_argnums=(0, 1))
+def splice_positions(pool_k, pool_v, rows_k, rows_v, blk, off):
+    """Per-position scatter for chunked prefill: position i of the
+    chunk (L, 1, S, H, Dh) lands in pool block ``blk[i]`` at row
+    ``off[i]`` (sentinel entries — the pad tail — are dropped). No
+    alignment requirement between chunk offsets and the block size: a
+    vlm image prefix can shift every chunk boundary. One compile per
+    chunk shape."""
+    return (MD.write_pool_positions(pool_k, rows_k, blk[None], off[None]),
+            MD.write_pool_positions(pool_v, rows_v, blk[None], off[None]))
+
+
+def _init_sharded(make, shardings_of):
+    """``make()``'s arrays created in place under ``shardings_of(shape)``:
+    each device allocates only its own shard. Building the whole cache
+    on one device and then ``device_put``-ing it would hold a full
+    unsharded copy there (beside that device's weights) at start-up."""
+    return jax.jit(make, out_shardings=shardings_of(jax.eval_shape(make)))()
+
+
+# ---------------------------------------------------------------------------
 # contiguous backend (the original layout, behind the protocol)
 # ---------------------------------------------------------------------------
 
@@ -431,55 +500,24 @@ class ContiguousCache:
     def __init__(self, cfg, ecfg, mesh=None):
         self.cfg = cfg
         B, C = ecfg.max_batch, ecfg.max_seq_len
-        self._cache = MD.init_cache(cfg, B, C)
         self.kv_partitions = 1
-        if mesh is not None:
+        if mesh is None:
+            self._cache = MD.init_cache(cfg, B, C)
+        else:
             # serve-mode mesh: batch over ``data``, heads over ``model``
             # (sequence-sharded fallback when heads don't divide) — the
             # same rule the dry-run lowers under, so the resident pool
             # lives sharded next to the attention heads that read it.
-            self._cache = jax.device_put(
-                self._cache,
-                SH.cache_shardings(
-                    mesh, jax.eval_shape(lambda: self._cache), cfg))
+            self._cache = _init_sharded(
+                lambda: MD.init_cache(cfg, B, C),
+                lambda shape: SH.cache_shardings(mesh, shape, cfg))
             if "k" in self._cache:
                 self.kv_partitions = kv_partition_count(self._cache["k"])
-        axes = MD.cache_batch_axes(self._cache)
         self._footprint = contiguous_kv_bytes(cfg, B, C)
         # occupancy, for the double-import guard: the dense layout has
         # no allocator to notice a clobber, so track which slots hold a
         # live (spliced or imported, not yet freed) stream explicitly
         self._occupied: set[int] = set()
-
-        def _splice(big, rows, slot):
-            out = {}
-            for name, b in big.items():
-                ax = axes[name]
-                if ax is None:
-                    out[name] = b
-                else:
-                    out[name] = jax.lax.dynamic_update_slice_in_dim(
-                        b, rows[name].astype(b.dtype), slot, ax)
-            return out
-
-        self._splice = jax.jit(_splice)  # slot is traced: one compile
-
-        def _splice_partial(ck, cv, rk, rv, slot, offset, n_valid):
-            # rk/rv (L, 1, S, H, Dh): chunk rows -> positions
-            # offset..offset+n_valid-1 of row ``slot``; the pad tail is
-            # scattered out of range and dropped (never clamped back
-            # onto real positions, unlike a dynamic_update_slice).
-            s, c = rk.shape[2], ck.shape[2]
-            pos = offset + jnp.arange(s)
-            pos = jnp.where(jnp.arange(s) < n_valid, pos, c)
-            ck = ck.at[:, slot, pos].set(rk[:, 0].astype(ck.dtype),
-                                         mode="drop")
-            cv = cv.at[:, slot, pos].set(rv[:, 0].astype(cv.dtype),
-                                         mode="drop")
-            return ck, cv
-
-        # slot/offset/n_valid traced: one compile per chunk shape
-        self._splice_partial = jax.jit(_splice_partial)
 
     def can_admit(self, n_prompt: int, budget: int, prompt=None) -> bool:
         return True  # every slot already owns full capacity
@@ -487,15 +525,15 @@ class ContiguousCache:
     def splice(self, rows: dict, slot: int, n_prompt: int,
                budget: int, prompt=None) -> None:
         self._occupied.add(slot)
-        self._cache = self._splice(self._cache, rows,
-                                   jnp.asarray(slot, jnp.int32))
+        self._cache = splice_rows(self._cache, rows,
+                                  jnp.asarray(slot, jnp.int32))
 
     def reserve(self, slot: int, n_prompt: int, budget: int) -> None:
         self._occupied.add(slot)  # capacity is pre-provisioned per slot
 
     def splice_partial(self, k_rows, v_rows, slot: int, offset: int,
                        n_valid: int) -> None:
-        self._cache["k"], self._cache["v"] = self._splice_partial(
+        self._cache["k"], self._cache["v"] = splice_chunk(
             self._cache["k"], self._cache["v"], k_rows, v_rows,
             jnp.asarray(slot, jnp.int32), jnp.asarray(offset, jnp.int32),
             jnp.asarray(n_valid, jnp.int32))
@@ -571,8 +609,8 @@ class ContiguousCache:
                 pad[2] = (0, arr.shape[2] - row.shape[2])
                 row = np.pad(row, pad)
             rows[name] = jnp.asarray(row)
-        self._cache = self._splice(self._cache, rows,
-                                   jnp.asarray(slot, jnp.int32))
+        self._cache = splice_rows(self._cache, rows,
+                                  jnp.asarray(slot, jnp.int32))
 
     def resident_kv_bytes(self) -> int:
         return self._footprint
@@ -611,15 +649,15 @@ class PagedCache:
         self.table_width = W = C // bs
         self.num_blocks = NB = ecfg.kv_blocks or ecfg.max_batch * W
         self._bytes_per_token = kv_bytes_per_token(cfg)
-        self._pool_k, self._pool_v = MD.init_paged_pools(cfg, NB, bs)
         self.kv_partitions = 1
-        if mesh is not None:
+        if mesh is None:
+            self._pool_k, self._pool_v = MD.init_paged_pools(cfg, NB, bs)
+        else:
             # heads over ``model``; block/position dims stay whole (a
             # position split would break the bitwise decode contract)
-            pk, pv = jax.eval_shape(lambda: (self._pool_k, self._pool_v))
-            self._pool_k, self._pool_v = jax.device_put(
-                (self._pool_k, self._pool_v),
-                SH.pool_shardings(mesh, (pk, pv)))
+            self._pool_k, self._pool_v = _init_sharded(
+                lambda: MD.init_paged_pools(cfg, NB, bs),
+                lambda shape: SH.pool_shardings(mesh, shape))
             self.kv_partitions = kv_partition_count(self._pool_k)
         B = ecfg.max_batch
         # NB is the sentinel "no block" id: jitted scatters drop it,
@@ -636,50 +674,6 @@ class PagedCache:
         self.prefix_hits = 0           # admissions with a nonzero match
         self.prefix_hit_tokens = 0     # prompt tokens served from cache
         self.prefix_lookup_tokens = 0  # prompt tokens across lookups
-
-        def _splice(pool_k, pool_v, rows_k, rows_v, blocks):
-            # rows (L, 1, C, H, Dh) -> per-block (L, W, bs, H, Dh);
-            # sentinel entries of ``blocks`` are dropped (pad blocks
-            # past the prompt are never stored).
-            L, _, _, H, Dh = rows_k.shape
-            rk = rows_k[:, 0].reshape(L, W, bs, H, Dh)
-            rv = rows_v[:, 0].reshape(L, W, bs, H, Dh)
-            pool_k = pool_k.at[:, blocks].set(
-                rk.astype(pool_k.dtype), mode="drop")
-            pool_v = pool_v.at[:, blocks].set(
-                rv.astype(pool_v.dtype), mode="drop")
-            return pool_k, pool_v
-
-        self._splice = jax.jit(_splice)  # fixed W: one compile total
-
-        def _splice_pos(pool_k, pool_v, rows_k, rows_v, blk, off):
-            # per-position scatter for chunked prefill: position i of
-            # the chunk lands in pool block ``blk[i]`` at row ``off[i]``
-            # (sentinel blk entries — the pad tail — are dropped). No
-            # alignment requirement between chunk offsets and the block
-            # size: a vlm image prefix can shift every chunk boundary.
-            pk = pool_k.at[:, blk, off].set(
-                rows_k[:, 0].astype(pool_k.dtype), mode="drop")
-            pv = pool_v.at[:, blk, off].set(
-                rows_v[:, 0].astype(pool_v.dtype), mode="drop")
-            return pk, pv
-
-        self._splice_pos = jax.jit(_splice_pos)  # one compile per chunk shape
-
-        def _import_blocks(pool_k, pool_v, rows_k, rows_v, blocks):
-            # handoff import: dense rows (L, 1, nblk*bs, H, Dh) -> the
-            # freshly allocated blocks of an imported slot. All entries
-            # of ``blocks`` are real (the importer allocates exactly the
-            # packet's span), so no sentinel handling is needed.
-            L, _, _, H, Dh = rows_k.shape
-            nblk = blocks.shape[0]
-            rk = rows_k[:, 0].reshape(L, nblk, bs, H, Dh)
-            rv = rows_v[:, 0].reshape(L, nblk, bs, H, Dh)
-            pool_k = pool_k.at[:, blocks].set(rk.astype(pool_k.dtype))
-            pool_v = pool_v.at[:, blocks].set(rv.astype(pool_v.dtype))
-            return pool_k, pool_v
-
-        self._import_blocks = jax.jit(_import_blocks)  # one per block count
 
     # -- accounting -------------------------------------------------------
     def _need_blocks(self, n_prompt: int, budget: int) -> int:
@@ -756,7 +750,7 @@ class PagedCache:
         self._reserved[slot] = self._need_blocks(n_prompt, budget) - now
         vec = np.full(self.table_width, self.num_blocks, np.int32)
         vec[:now] = blocks
-        self._pool_k, self._pool_v = self._splice(
+        self._pool_k, self._pool_v = splice_blocks(
             self._pool_k, self._pool_v, rows["k"], rows["v"],
             jnp.asarray(vec))
         if self.prefix is not None and prompt is not None:
@@ -832,7 +826,7 @@ class PagedCache:
         blk = np.full(s, self.num_blocks, np.int32)
         valid = np.arange(s) < n_valid
         blk[valid] = self.table[slot, pos[valid] // bs]
-        self._pool_k, self._pool_v = self._splice_pos(
+        self._pool_k, self._pool_v = splice_positions(
             self._pool_k, self._pool_v, k_rows, v_rows,
             jnp.asarray(blk), jnp.asarray(pos % bs, np.int32))
 
@@ -921,10 +915,8 @@ class PagedCache:
         bs = self.block_size
         nblk = max(1, math.ceil(max(int(n_valid), 1) / bs))
         idx = jnp.asarray(self.table[slot, :nblk], jnp.int32)
-        l = self._pool_k.shape[0]
-        tail = self._pool_k.shape[3:]
-        k = self._pool_k[:, idx].reshape(l, 1, nblk * bs, *tail)
-        v = self._pool_v[:, idx].reshape(l, 1, nblk * bs, *tail)
+        k = gather_blocks(self._pool_k, idx)
+        v = gather_blocks(self._pool_v, idx)
         packet = {"n_valid": int(n_valid),
                   "k": np.asarray(jax.device_get(k)),
                   "v": np.asarray(jax.device_get(v))}
@@ -982,7 +974,7 @@ class PagedCache:
             pad[2] = (0, span - rows_k.shape[2])
             rows_k = np.pad(rows_k, pad)
             rows_v = np.pad(rows_v, pad)
-        self._pool_k, self._pool_v = self._import_blocks(
+        self._pool_k, self._pool_v = splice_blocks(
             self._pool_k, self._pool_v,
             jnp.asarray(rows_k[:, :, h * bs:span]),
             jnp.asarray(rows_v[:, :, h * bs:span]),

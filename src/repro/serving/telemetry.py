@@ -573,6 +573,8 @@ def dispatch_calibration(engines, telemetry: "Telemetry",
     """
     # costmodel imports serving.engine which imports this module —
     # resolve the cycle by importing lazily at call time.
+    import jax
+
     from repro.core import costmodel as CM
     from repro.core import trace as T
 
@@ -581,6 +583,11 @@ def dispatch_calibration(engines, telemetry: "Telemetry",
     if profile is not None:
         peak_ops = profile.ops_per_s
         peak_bw = profile.mem_bw_gbs * 1e9
+    elif jax.default_backend() == "tpu":
+        raise ValueError(
+            "dispatch_calibration on a TPU needs an explicit profile: the "
+            "host reference peaks describe a CPU, and ratios against them "
+            "would be reported as if they described the chip")
     else:
         peak_ops, peak_bw = HOST_REF_OPS_PER_S, HOST_REF_BYTES_PER_S
 

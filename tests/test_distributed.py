@@ -4,11 +4,16 @@ device count)."""
 from __future__ import annotations
 
 import json
+import os
 import subprocess
 import sys
 import textwrap
 
 import pytest
+
+# the children compile on CPU placeholder devices; pinned here rather
+# than inherited, so a parent on a chip machine cannot hand them the TPU
+CPU_CHILD_ENV = dict(os.environ, JAX_PLATFORMS="cpu")
 
 SUBPROCESS_SCRIPT = r"""
 import os
@@ -22,7 +27,6 @@ from repro.configs import registry
 from repro.distributed import hints
 from repro.distributed import sharding as SH
 from repro.launch import steps as ST
-from repro.launch.dryrun import peak_memory_bytes
 from repro.launch.mesh import make_mesh
 from repro.models import model as MD
 from repro.optim import AdamW, OptConfig
@@ -44,7 +48,7 @@ for arch in %(archs)s:
         lowered = jax.jit(step, in_shardings=(p_sh, o_sh, b_sh)).lower(
             params_shape, opt_shape, batch)
         compiled = lowered.compile()
-        out[arch] = peak_memory_bytes(compiled.memory_analysis())
+        out[arch] = compiled.memory_analysis().peak_memory_in_bytes
 print("RESULT " + json.dumps(out))
 """
 
@@ -53,7 +57,8 @@ def run_sub(archs, mesh, axes):
     script = SUBPROCESS_SCRIPT % {
         "archs": repr(archs), "mesh": repr(mesh), "axes": repr(axes)}
     r = subprocess.run([sys.executable, "-c", script],
-                       capture_output=True, text=True, timeout=900)
+                       capture_output=True, text=True, timeout=900,
+                       env=CPU_CHILD_ENV)
     assert r.returncode == 0, r.stderr[-3000:]
     line = [l for l in r.stdout.splitlines() if l.startswith("RESULT ")][-1]
     return json.loads(line[len("RESULT "):])
@@ -84,7 +89,6 @@ from repro.configs import registry
 from repro.distributed import hints
 from repro.distributed import sharding as SH
 from repro.launch import steps as ST
-from repro.launch.dryrun import peak_memory_bytes
 from repro.launch.mesh import make_mesh
 from repro.models import model as MD
 
@@ -114,7 +118,7 @@ for arch in %(archs)s:
                            out_shardings=(t_sh, None, c_sh),
                            donate_argnums=(2,)).lower(
             params_shape, tokens, cache_shape).compile()
-        out[arch] = peak_memory_bytes(compiled.memory_analysis())
+        out[arch] = compiled.memory_analysis().peak_memory_in_bytes
 print("RESULT " + json.dumps(out))
 """
 
@@ -128,7 +132,8 @@ def test_serve_decode_step_compiles_on_8dev_mesh():
         "archs": repr(["qwen1.5-0.5b", "deepseek-moe-16b"]),
         "mesh": repr((2, 4)), "axes": repr(("data", "model"))}
     r = subprocess.run([sys.executable, "-c", script],
-                       capture_output=True, text=True, timeout=900)
+                       capture_output=True, text=True, timeout=900,
+                       env=CPU_CHILD_ENV)
     assert r.returncode == 0, r.stderr[-3000:]
     line = [l for l in r.stdout.splitlines()
             if l.startswith("RESULT ")][-1]

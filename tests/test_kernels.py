@@ -346,3 +346,16 @@ def test_pallas_flash_matches_chunked_impl():
     want = attention(q, k, v, impl="chunked", q_chunk=128, kv_chunk=128)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-4, rtol=2e-4)
+
+
+def test_interpret_mode_is_decided_by_the_backend(monkeypatch):
+    """Kernels interpret exactly off a TPU; on a TPU they always compile
+    unless a caller asks otherwise explicitly."""
+    from repro.kernels import interpret_mode
+    assert interpret_mode() is (jax.default_backend() != "tpu")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert interpret_mode() is False
+    assert interpret_mode(True) is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert interpret_mode() is True
+    assert interpret_mode(False) is False

@@ -5,10 +5,15 @@ per-device KV accounting."""
 from __future__ import annotations
 
 import json
+import os
 import subprocess
 import sys
 
 import pytest
+
+# the children compile on CPU placeholder devices; pinned here rather
+# than inherited, so a parent on a chip machine cannot hand them the TPU
+CPU_CHILD_ENV = dict(os.environ, JAX_PLATFORMS="cpu")
 
 SUBPROCESS_SCRIPT = r"""
 import os
@@ -62,7 +67,8 @@ print("RESULT " + json.dumps(out))
 def run_sub(cases):
     script = SUBPROCESS_SCRIPT % {"cases": repr(cases)}
     r = subprocess.run([sys.executable, "-c", script],
-                       capture_output=True, text=True, timeout=1800)
+                       capture_output=True, text=True, timeout=1800,
+                       env=CPU_CHILD_ENV)
     assert r.returncode == 0, r.stderr[-3000:]
     line = [l for l in r.stdout.splitlines() if l.startswith("RESULT ")][-1]
     return json.loads(line[len("RESULT "):])

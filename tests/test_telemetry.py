@@ -233,9 +233,10 @@ def test_profiler_joins_every_dispatch(setup, kv_cache, scheduler):
         assert row["achieved_flops_per_s"] >= 0, kind
 
 
-def test_calibration_respects_hardware_profile(setup):
+def test_calibration_respects_hardware_profile(setup, monkeypatch):
     """predicted_s scales with the profile roofline: a faster profile
-    predicts less time, so the measured/predicted ratio grows."""
+    predicts less time, so the measured/predicted ratio grows. On a TPU
+    the host reference peaks are refused: a profile must be given."""
     from repro.core import profiles as HW
     cfg, params = setup
     tel = Telemetry()
@@ -245,6 +246,10 @@ def test_calibration_respects_hardware_profile(setup):
     for kind in host:
         assert pim[kind]["predicted_s"] != host[kind]["predicted_s"]
         assert math.isfinite(pim[kind]["model_error_ratio"])
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="explicit profile"):
+        dispatch_calibration(eng, tel)
+    assert dispatch_calibration(eng, tel, profile=HW.PIM_AI_CHIP) == pim
 
 
 # ---------------------------------------------------------------------------
