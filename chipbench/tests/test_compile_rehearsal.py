@@ -1,0 +1,125 @@
+"""Compile rehearsal of each cell for one TPU v5e, with no chip attached:
+the engine's decode program and its largest prefill program, at the
+configuration's published widths and the cell's slots and capacity,
+compiled for a described ``v5e:2x2`` topology. The compiler refuses
+here what the chip would refuse, and ``memory_analysis()`` says whether
+the program, with the resident weights and KV cache among its operands,
+fits one chip's HBM.
+
+The topology is described inside a module fixture, never at import
+time: only one process may load the TPU library, and the test workers
+each import this file.
+"""
+from __future__ import annotations
+
+import json
+import os
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from chipbench import loadgen, system
+from chipbench.families import dense
+
+ROOT = Path(__file__).resolve().parents[2]
+HBM_BYTES = int(15.75 * 2**30)   # what XLA lets one v5e program use
+
+
+def _cells():
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    files = {c["name"]: c["file"] for c in bench["configs"]}
+    return [(w["name"], files[w["config"]], w["traffic"])
+            for w in bench["workloads"] if w["chips"] == 1]
+
+
+CELLS = _cells()
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    # a compile for a described chip is written to a persistent cache but
+    # cannot be read back without one: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "no TPU lib"
+        jax.config.update("jax_enable_compilation_cache", was)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _programs(c: dict, mix: dict, sh):
+    """(name, jitted closure, operand shapes, bytes resident beside the
+    operands) of the cell's decode program and its largest prefill
+    program."""
+    system.import_program()
+    from repro.models import model as MD
+    from repro.serving.engine import CACHE_ARGNUM, build_closures
+
+    arch = system.arch_config(c)
+    e = c["engine"]
+    B, C = int(e["max_batch"]), int(e["max_seq_len"])
+    closures = build_closures(arch, C)
+
+    def sds(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh)
+
+    params = jax.tree.map(sds, jax.eval_shape(
+        lambda: dense.program_params(dense.make_weights(
+            c, 0, dtype=c["torch_dtype"]))))
+    i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=sh)
+    toks = jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=sh)
+    pos = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=sh)
+    live = jax.ShapeDtypeStruct((B,), jnp.bool_, sharding=sh)
+    if e["kv_cache"] == "paged":
+        bs = int(e["kv_block_size"])
+        cache = jax.tree.map(sds, MD.paged_cache_spec(arch, B, C, bs,
+                                                      ragged=False))
+        cache["len"] = i32
+    else:
+        cache = jax.tree.map(sds, MD.cache_spec(arch, B, C))
+    decode = ("decode", jax.jit(closures["decode"],
+                                donate_argnums=CACHE_ARGNUM),
+              (params, toks, cache, pos, live), 0)
+    if e["scheduler"] == "chunked":
+        ct = int(e["chunk_tokens"])
+        batch = {"tokens": jax.ShapeDtypeStruct((1, ct), jnp.int32,
+                                                sharding=sh)}
+        table = jax.ShapeDtypeStruct((C // int(e["kv_block_size"]),),
+                                     jnp.int32, sharding=sh)
+        pre = ("chunk_paged", jax.jit(closures["chunk_paged"]),
+               (params, batch, cache["k"], cache["v"], table, i32, i32), 0)
+    else:
+        n = int(mix["prompt"]["max"])   # the longest prompt of the mix
+        batch = {"tokens": jax.ShapeDtypeStruct((1, n), jnp.int32,
+                                                sharding=sh)}
+        kv = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(cache))
+        pre = ("prefill", jax.jit(closures["prefill"]),
+               (params, batch, i32, i32), kv)
+    return [decode, pre]
+
+
+@pytest.mark.parametrize("cell,path,mix", CELLS,
+                         ids=[cell[0] for cell in CELLS])
+def test_cell_programs_fit_one_chip(one_chip, cell, path, mix):
+    c = json.loads((ROOT / path).read_text())
+    for name, fn, args, resident in _programs(c, loadgen.load_mix(mix),
+                                              one_chip):
+        m = fn.lower(*args).compile().memory_analysis()
+        total = (m.argument_size_in_bytes + m.output_size_in_bytes
+                 - m.alias_size_in_bytes + m.temp_size_in_bytes + resident)
+        print(f"{cell} {name}: arguments {m.argument_size_in_bytes} "
+              f"output {m.output_size_in_bytes} alias "
+              f"{m.alias_size_in_bytes} temp {m.temp_size_in_bytes} "
+              f"resident KV beside it {resident} total {total}")
+        assert total < HBM_BYTES, (cell, name, total)
