@@ -49,13 +49,15 @@ crossed worker boundaries; greedy outputs stay bitwise identical to the
 single engine.
 
 Telemetry (``--telemetry``): the backend-comparison engines share one
-``Telemetry`` hub — every engine phase (admit, prefill, decode
-dispatch, KV commit/splice, sampling, retire) records a nested span and
-every jitted dispatch is wall-timed with ``block_until_ready``. The
-demo prints the top-5 slowest spans and the per-kind achieved-vs-
-predicted calibration table that joins those wall times against the
-static cost model's traced FLOPs/bytes — outputs stay bitwise
-identical with telemetry on.
+``Telemetry`` hub — every engine phase (step, scheduler admit/retire,
+each dispatch's enqueue, KV views/commits/splices, sampling, readback)
+records a nested span, and each dispatch span carries the work it
+enqueued (rows, attended keys, KV positions written). The demo prints
+the top-5 slowest spans and the work per dispatch kind. Under a
+running ``jax.profiler`` trace the same spans are profiler annotations
+beside the device operations, whose layer scopes (attention, kv_write,
+mlp, logits) give each dispatch's device time by layer — outputs stay
+bitwise identical with telemetry on.
 
 Run:  PYTHONPATH=src python examples/serve_batched.py
       PYTHONPATH=src python examples/serve_batched.py --telemetry
@@ -75,8 +77,7 @@ from repro.core import profiles as HW
 from repro.core.simulator import LLMSimulator, SimConfig
 from repro.models import model as MD
 from repro.serving import (ClusterConfig, ClusterEngine, EngineConfig,
-                           ServingEngine, Telemetry, dispatch_calibration,
-                           format_calibration)
+                           ServingEngine, Telemetry)
 
 
 def main():
@@ -93,8 +94,8 @@ def main():
     ap.add_argument("--telemetry", action="store_true",
                     help="instrument the backend-comparison runs with a "
                          "shared Telemetry hub and print the top-5 "
-                         "slowest spans plus the per-kind achieved-vs-"
-                         "predicted dispatch calibration table")
+                         "slowest spans plus the work each dispatch "
+                         "kind enqueued")
     args = ap.parse_args()
 
     cfg = registry.get_smoke_config("phi3-mini-3.8b")
@@ -107,14 +108,12 @@ def main():
           f"({args.scheduler} scheduler)...")
 
     tel = Telemetry() if args.telemetry else None
-    tel_engines = []
     outputs = {}
     for kv in ("contiguous", "paged"):
         eng = ServingEngine(params, cfg, EngineConfig(
             max_batch=4, max_seq_len=96, max_new_tokens=12, kv_cache=kv,
             scheduler=args.scheduler, chunk_tokens=16,
             spec_gamma=args.gamma), telemetry=tel, telemetry_label=kv)
-        tel_engines.append(eng)
         for p in prompts:
             eng.submit(p)
         eng.run()
@@ -133,20 +132,29 @@ def main():
     print(f"\npaged outputs bitwise-match contiguous: "
           f"{outputs['paged'] == outputs['contiguous']}")
 
-    # -- telemetry: slowest spans + the measured-vs-predicted loop ----------
-    # every engine phase above was wrapped in a span and every jitted
-    # dispatch was wall-timed; join those wall times against the static
-    # cost model's traced FLOPs/bytes for the exact same dispatch-log
-    # entries and the model-error column tells you how far the jaxpr
-    # cost model is from this machine (CI only gates finiteness).
+    # -- telemetry: slowest spans + the work each dispatch enqueued ---------
+    # every engine phase above was wrapped in a span; a dispatch span
+    # covers the enqueue only (nothing waits on the device), so the
+    # device's own time per dispatch and per layer comes from a
+    # jax.profiler trace taken around the same run
     if tel is not None:
         print(f"\ntelemetry: {len(tel.tracer.spans)} spans across "
-              f"{len(tel_engines)} engines; top-5 slowest:")
+              "the 2 engines; top-5 slowest:")
         for s in tel.tracer.slowest(5):
             print(f"  {s.wall_dur_s*1e3:9.2f} ms  [{s.tid}] "
                   f"{'  ' * s.depth}{s.name} ({s.cat})")
-        print("\ndispatch calibration (host reference roofline):")
-        print(format_calibration(dispatch_calibration(tel_engines, tel)))
+        work = {}
+        for s in tel.tracer.spans:
+            if s.cat == "dispatch":
+                w = work.setdefault(s.name, [0, 0, 0, 0])
+                w[0] += 1
+                for j, k in enumerate(("rows", "keys", "new_tokens")):
+                    w[j + 1] += s.labels[k]
+        print("\ndispatch work: span, dispatches, rows, attended keys, "
+              "KV positions written")
+        for name in sorted(work):
+            print(f"  {name:<32} " + " ".join(f"{v:>8}"
+                                              for v in work[name]))
 
     # -- scheduling: head-of-line blocking demo -----------------------------
     # one 72-token prompt queued ahead of the shorts: under the blocking

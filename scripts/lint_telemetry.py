@@ -4,18 +4,18 @@ Two checks, both fatal (nonzero exit):
 
 1. **Static (AST)** — in ``serving/engine.py``, every call to
    ``self._log_dispatch`` must occur inside ``ServingEngine._dispatch``.
-   ``_dispatch`` is the single site that logs the dispatch, opens the
-   span named after the kind, and records the profiler sample; a bare
-   ``_log_dispatch`` call anywhere else is a dispatch the span tracer
-   and the measured-vs-predicted calibration would silently miss.
+   ``_dispatch`` is the single site that logs the dispatch and opens
+   its ``engine.dispatch.<kind>`` span (a profiler annotation when
+   telemetry is on); a bare ``_log_dispatch`` call anywhere else is a
+   dispatch the span tracer and the device trace would silently miss.
 
 2. **Runtime** — drive mini engines (blocking / chunked / speculative,
    both KV backends split across them) with a live ``Telemetry`` hub
-   and require that (a) every kind appearing in ``dispatch_log`` also
-   appears as a ``cat="dispatch"`` span name on that engine's track,
-   and (b) the dispatch profiler joined 100% of ``dispatch_log`` —
-   i.e. the kinds the cost model prices are exactly the kinds the
-   telemetry layer measures.
+   and require that (a) the engine made dispatches, and (b) every
+   logged kind has its dispatch span: the ``engine.dispatch.<kind>``
+   spans on that engine's track are, in order, exactly its
+   ``dispatch_log`` — the kinds the cost model prices are the kinds
+   the device trace names.
 
 Usage: python scripts/lint_telemetry.py [--skip-runtime]
 """
@@ -78,8 +78,7 @@ def lint_runtime() -> list[str]:
 
     from repro.configs import registry
     from repro.models import model as MD
-    from repro.serving import (EngineConfig, ServingEngine, Telemetry,
-                               join_coverage)
+    from repro.serving import EngineConfig, ServingEngine, Telemetry
 
     cfg = registry.get_smoke_config(MODEL).replace(dtype="float32")
     params = MD.init_params(jax.random.PRNGKey(0), cfg)
@@ -100,22 +99,19 @@ def lint_runtime() -> list[str]:
         for n in (5, 9):
             eng.submit(rng.integers(0, cfg.vocab_size, size=n))
         eng.run()
-        logged = {e["kind"] for e in eng.dispatch_log}
-        spanned = {s.name for s in tel.tracer.spans
-                   if s.tid == label and s.cat == "dispatch"}
-        missing = logged - spanned
-        if missing:
-            problems.append(
-                f"{label}: dispatch kinds {sorted(missing)} logged but "
-                "never spanned")
+        logged = [f"engine.dispatch.{e['kind']}" for e in eng.dispatch_log]
+        spanned = [s.name for s in sorted(tel.tracer.spans,
+                                          key=lambda s: s.index)
+                   if s.tid == label and s.cat == "dispatch"]
         if not logged:
             problems.append(f"{label}: engine made no dispatches — "
                             "workload too small to lint")
-        joined, total = join_coverage(eng, tel)
-        if joined != total:
+        if spanned != logged:
+            missing = sorted(set(logged) - set(spanned))
             problems.append(
-                f"{label}: profiler joined {joined}/{total} "
-                "dispatch-log entries")
+                f"{label}: {len(spanned)} dispatch spans for "
+                f"{len(logged)} dispatch-log entries (kinds never "
+                f"spanned: {missing})")
     return problems
 
 

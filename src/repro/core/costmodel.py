@@ -227,12 +227,12 @@ def entry_tracer(engine):
     This is the exact join the drift audit uses: ``kind`` selects the
     engine's own jitted closure (``draft_*`` kinds route to the draft
     model's closures and params), and the entry's operand spec tree is
-    re-traced through it. Shared by :func:`audit_engine` and the
-    telemetry layer's measured-vs-predicted calibration
-    (``repro.serving.telemetry.dispatch_calibration``), so the seconds
-    the profiler measured and the FLOPs/bytes the model predicts refer
-    to the same compiled graph. Raises ``KeyError`` for a kind with no
-    closure.
+    re-traced through it, so what :func:`audit_engine` checks is the
+    graph the engine dispatched under that entry's
+    ``engine.dispatch.<kind>`` span. The device time of each dispatch
+    is measured from a ``jax.profiler`` trace of those spans and their
+    programs (``repro.serving.telemetry``), never by blocking on it.
+    Raises ``KeyError`` for a kind with no closure.
     """
     closures = engine._closures
     draft_closures = getattr(engine, "_draft_closures", None)

@@ -168,6 +168,7 @@ def embed_tokens(p, tokens):
     return jnp.take(p["table"], tokens, axis=0)
 
 
+@jax.named_scope("logits")
 def logits_from_hidden(head_table, x):
     """head_table: (V, d). Returns fp32 logits."""
     return jnp.einsum(

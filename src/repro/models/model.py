@@ -79,6 +79,7 @@ def init_attn(key, cfg):
     return p
 
 
+@jax.named_scope("attn_proj")
 def _proj_qkv(p, cfg, x, kv_x=None):
     """x: (B,S,d). Returns q (B,S,Hq,Dh), k/v (B,Skv,Hkv,Dh)."""
     b, s, _ = x.shape
@@ -110,11 +111,12 @@ def attn_full(p, cfg, x, *, positions, causal=True, window=None,
         q = L.apply_rope(q, positions, cfg.rope_theta)
         kp = positions if kv_positions is None else kv_positions
         k = L.apply_rope(k, kp, cfg.rope_theta)
-    o = attention(q, k, v, causal=causal, window=window,
-                  q_offset=0, kv_offset=0, impl=attn_impl,
-                  q_chunk=cfg.attn_chunk, kv_chunk=cfg.attn_chunk)
+    with jax.named_scope("attention"):
+        o = attention(q, k, v, causal=causal, window=window,
+                      q_offset=0, kv_offset=0, impl=attn_impl,
+                      q_chunk=cfg.attn_chunk, kv_chunk=cfg.attn_chunk)
     o = hints.row_input(o.reshape(x.shape[0], x.shape[1], -1))
-    return jnp.einsum("bse,ed->bsd", o, p["wo"]), (k, v)
+    return _out_proj(p, o), (k, v)
 
 
 def attn_decode(p, cfg, x, k_cache, v_cache, cache_len, *, window=None,
@@ -133,11 +135,12 @@ def attn_decode(p, cfg, x, k_cache, v_cache, cache_len, *, window=None,
             jnp.full((1,), clen, jnp.int32)
         q = L.apply_rope(q, pos, cfg.rope_theta)
         k1 = L.apply_rope(k1, pos, cfg.rope_theta)
-    o = decode_attention(q, k_cache, v_cache, cache_len, window=window,
-                         extra_k=k1, extra_v=v1,
-                         block_tables=block_tables)
+    with jax.named_scope("attention"):
+        o = decode_attention(q, k_cache, v_cache, cache_len, window=window,
+                             extra_k=k1, extra_v=v1,
+                             block_tables=block_tables)
     o = hints.row_input(o.reshape(x.shape[0], 1, -1))
-    return jnp.einsum("bse,ed->bsd", o, p["wo"]), k1, v1
+    return _out_proj(p, o), k1, v1
 
 
 def attn_chunk(p, cfg, x, k_hist, v_hist, hist_len, *, positions,
@@ -152,9 +155,15 @@ def attn_chunk(p, cfg, x, k_hist, v_hist, hist_len, *, positions,
         q = L.apply_rope(q, positions, cfg.rope_theta)
         k = L.apply_rope(k, positions, cfg.rope_theta)
     impl = "pallas" if attn_impl == "pallas" else "chunked"
-    o = prefill_over_cache(q, k_hist, v_hist, hist_len, k, v, impl=impl)
+    with jax.named_scope("attention"):
+        o = prefill_over_cache(q, k_hist, v_hist, hist_len, k, v, impl=impl)
     o = hints.row_input(o.reshape(x.shape[0], x.shape[1], -1))
-    return jnp.einsum("bse,ed->bsd", o, p["wo"]), (k, v)
+    return _out_proj(p, o), (k, v)
+
+
+@jax.named_scope("attn_proj")
+def _out_proj(p, o):
+    return jnp.einsum("bse,ed->bsd", o, p["wo"])
 
 
 # ---------------------------------------------------------------------------
@@ -178,6 +187,7 @@ def init_decoder_layer(key, cfg, ffn_kind="dense", d_ff=None, cross=False):
     return p
 
 
+@jax.named_scope("mlp")
 def _apply_ffn(p, cfg, x):
     if "moe" in p:
         return M.apply_moe(p["moe"], cfg, x)
@@ -235,10 +245,11 @@ def decoder_block_decode(p, cfg, x, k_cache, v_cache, cache_len, *,
     if cross_k is not None:
         h = L.apply_norm(p["ln_x"], cfg, x)
         q, _, _ = _proj_qkv(p["xattn"], cfg, h)
-        o = decode_attention(q, cross_k, cross_v,
-                             cross_k.shape[1])  # all slots valid
+        with jax.named_scope("attention"):
+            o = decode_attention(q, cross_k, cross_v,
+                                 cross_k.shape[1])  # all slots valid
         o = hints.row_input(o.reshape(x.shape[0], 1, -1))
-        x = x + jnp.einsum("bse,ed->bsd", o, p["xattn"]["wo"])
+        x = x + _out_proj(p["xattn"], o)
     h = L.apply_norm(p["ln2"], cfg, x)
     x = hints.hidden(x + _apply_ffn(p, cfg, h), cfg.act_shard)
     return x, k1, v1
@@ -551,6 +562,7 @@ def paged_cache_spec(cfg, batch_size, capacity, block_size,
 # prefill
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("kv_write")
 def _write_kv(cache_arr, kv, start):
     """cache_arr (L,B,C,H,Dh) <- kv (L,B,S,H,Dh) at slot ``start``."""
     return jax.lax.dynamic_update_slice(
@@ -609,11 +621,12 @@ def prefill(params, cfg, batch, capacity, *, attn_impl="chunked",
             # window tail (tokens s-c .. s-1) rotated by s % c.
             ks, vs = ks[:, :, s - c:], vs[:, :, s - c:]
             shift = s % c
-            if shift:
-                ks = jnp.roll(ks, shift, axis=2)
-                vs = jnp.roll(vs, shift, axis=2)
-            cache["k"], cache["v"] = (ks.astype(cache["k"].dtype),
-                                      vs.astype(cache["v"].dtype))
+            with jax.named_scope("kv_write"):
+                if shift:
+                    ks = jnp.roll(ks, shift, axis=2)
+                    vs = jnp.roll(vs, shift, axis=2)
+                cache["k"], cache["v"] = (ks.astype(cache["k"].dtype),
+                                          vs.astype(cache["v"].dtype))
         else:
             cache["k"] = _write_kv(cache["k"], ks, 0)
             cache["v"] = _write_kv(cache["v"], vs, 0)
@@ -725,6 +738,7 @@ def prefill_chunk(params, cfg, batch, k_hist, v_hist, hist_len, *,
     positions = jnp.asarray(hist_len, jnp.int32) + jnp.arange(s)
     n_first = len(params.get("first_layers", []))
 
+    @jax.named_scope("attention")
     def view(layer_hist):
         if block_table is None:
             return layer_hist
@@ -765,6 +779,7 @@ def prefill_chunk(params, cfg, batch, k_hist, v_hist, hist_len, *,
 # decode step
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("kv_write")
 def write_window(cache_arr, kv, row, pos, n_valid):
     """Write one row's S tokens of KV ``kv`` (L|G, 1, S, H, Dh) into row
     ``row`` of ``cache_arr`` (L|G, B, C, H, Dh) at positions ``pos ..
@@ -794,6 +809,7 @@ def write_window(cache_arr, kv, row, pos, n_valid):
         cache_arr, jnp.where(keep[None, None, :, None, None], new, old), idx)
 
 
+@jax.named_scope("kv_write")
 def _write_tokens_kv(cache_arr, kv, pos, live=None):
     """Write S new tokens' KV ``kv`` (L|G, B, S, H, Dh) into
     ``cache_arr`` (L|G, B, C, H, Dh) at per-row positions ``pos[b] ..
@@ -811,6 +827,7 @@ def _write_tokens_kv(cache_arr, kv, pos, live=None):
     return cache_arr
 
 
+@jax.named_scope("kv_write")
 def write_pool_positions(pool, kv, blk, off):
     """Write tokens' KV ``kv`` (L, R, S, H, Dh) into the shared block
     pool (L, NB, bs, H, Dh): token (r, t) lands in block ``blk[r, t]``
@@ -835,6 +852,7 @@ def write_pool_positions(pool, kv, blk, off):
     return jax.lax.fori_loop(0, r * s, body, pool)
 
 
+@jax.named_scope("kv_write")
 def write_pool_blocks(pool, rows, blocks):
     """Dense rows (L, 1, n*bs, H, Dh) -> pool blocks ``blocks`` (n,):
     block i of the rows lands in pool block ``blocks[i]``; sentinel
@@ -856,6 +874,7 @@ def write_pool_blocks(pool, rows, blocks):
     return jax.lax.fori_loop(0, blocks.shape[0], body, pool)
 
 
+@jax.named_scope("kv_write")
 def _write_tokens_kv_paged(pool, kv, block_tab, pos, live=None):
     """Paged analogue of :func:`_write_tokens_kv`: S tokens' KV ``kv``
     (L, B, S, H, Dh) land in the shared block pool (L, NB, bs, H, Dh) at
@@ -1073,6 +1092,7 @@ def verify_tokens(params, cfg, tokens, cache, *, live=None,
     positions = n[:, None] + jnp.arange(s)                  # (B, S)
     btab = cache.get("block_tab")
 
+    @jax.named_scope("attention")
     def hist_view(kc, vc):
         if btab is None:
             return kc, vc

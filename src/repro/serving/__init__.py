@@ -32,13 +32,9 @@ from repro.serving.kv_cache import (  # noqa: F401
 )
 from repro.serving.telemetry import (  # noqa: F401
     NULL_TELEMETRY,
-    DispatchProfiler,
     MetricsRegistry,
     SpanTracer,
     Telemetry,
-    dispatch_calibration,
-    format_calibration,
-    join_coverage,
     merge_snapshots,
     validate_trace_events,
 )

@@ -297,10 +297,10 @@ class ClusterEngine:
         (least-loaded router), then run one engine step on every decode
         worker that holds live slots."""
         self.steps += 1
-        with self._span("cluster_step", step=self.steps):
+        with self._span("cluster.step", step=self.steps):
             if (self.ccfg.autoscale
                     and self.steps % self.ccfg.autoscale_interval == 0):
-                with self._span("autoscale"):
+                with self._span("cluster.autoscale"):
                     self._autoscale()
             if self.ccfg.slo_aware and len(self.waiting) > 1:
                 now = self._now()
@@ -308,9 +308,9 @@ class ClusterEngine:
                                  key=lambda r: slo_sort_key(r, now))
                 self.waiting.clear()
                 self.waiting.extend(ordered)
-            with self._span("admit"):
+            with self._span("cluster.schedule.admit"):
                 self._admit_prefills()
-            with self._span("route"):
+            with self._span("cluster.route"):
                 self._place_pending()
             for w in self.decode_workers:
                 if not w.alive or not w.live_slots():
@@ -496,7 +496,8 @@ class ClusterEngine:
         same ``_pack_slot`` snapshot the SLO policy uses to preempt)."""
         eng = w.eng
         req = eng.slot_req[slot]
-        with self._span("migration" if migration else "handoff",
+        with self._span("cluster.kv.migration" if migration
+                        else "cluster.kv.handoff",
                         cat="kv", rid=req.rid, worker=w.idx):
             with w.ctx():
                 pkt = eng._pack_slot(slot)

@@ -115,6 +115,24 @@ from repro.serving.telemetry import NULL_TELEMETRY
 CACHE_ARGNUM = 2
 
 
+def attended_keys(starts, n: int, window) -> int:
+    """Keys a dispatch's queries attend: ``n`` tokens of each row fed
+    at positions ``p .. p + n - 1`` for every ``p`` in ``starts``, the
+    token at position ``q`` seeing ``min(q + 1, window)`` keys (itself
+    and the cached ones before it)."""
+    p = np.asarray(starts, np.int64)
+    m = np.full_like(p, n) if window is None else np.clip(window - p, 0, n)
+    rest = 0 if window is None else (n - m) * window
+    return int(np.sum(m * p + m * (m + 1) // 2 + rest))
+
+
+def rows_work(pos, n: int, window, **extra) -> dict:
+    """Dispatch-span arguments of rows starting at positions ``pos`` and
+    feeding ``n`` tokens each: ``rows``, ``keys``, ``new_tokens``."""
+    return {"rows": len(pos), "keys": attended_keys(pos, n, window),
+            "new_tokens": len(pos) * n, **extra}
+
+
 def build_closures(cfg, capacity: int, *, masked: bool | None = None):
     """The engine's jitted dispatch graphs, as plain functions of
     ``(params, *operands)``, keyed by dispatch kind.
@@ -296,6 +314,7 @@ class Request:
     # filled by the engine:
     output: list = field(default_factory=list)
     t_submit: float = 0.0
+    t_admit: float = 0.0               # popped for admission (last pop)
     t_first: float = 0.0
     t_done: float = 0.0
     truncated_from: int | None = None  # original prompt length, if clipped
@@ -351,6 +370,7 @@ class SlotPacket:
     budget: int       # generation budget
     kv: dict          # export_slot payload (host arrays + metadata)
     hops: int = 0     # migrations this stream has survived
+    t_last: float = 0.0  # last token's stamp (telemetry's ITL gaps)
 
 
 def request_breakdowns(done) -> dict:
@@ -392,7 +412,7 @@ class ServingEngine:
         self.cfg = cfg
         self.ecfg = ecfg
         # observability: a shared serving.telemetry.Telemetry hub (span
-        # tracer + metrics + dispatch profiler). Defaults to the
+        # tracer + metrics; spans are profiler annotations). Defaults to the
         # disabled singleton — every hook then short-circuits to a
         # no-op, so the hot path pays one attribute load + branch and
         # outputs stay bitwise identical either way.
@@ -432,6 +452,7 @@ class ServingEngine:
         self.slot_rid = np.zeros(B, np.int32)     # sampling stream ids
         self.slot_seed = np.zeros(B, np.int32)
         self.slot_nprompt = np.zeros(B, np.int32)  # prompt len at bind
+        self.slot_t_last = np.zeros(B)            # last token (telemetry)
         self.waiting: deque[Request] = deque()
         self.finished: list[Request] = []
         self._next_rid = 0
@@ -689,30 +710,44 @@ class ServingEngine:
         return self.telemetry.span(name, cat=cat, tid=self.tel_label,
                                    now_fn=self._vnow, **labels)
 
-    def _dispatch(self, kind: str, fn, params, *args):
+    def _dispatch(self, kind: str, fn, params, *args, **span_args):
         """Issue one jitted dispatch: always append the audit-log entry;
-        with telemetry enabled, additionally time the dispatch to
-        completion (``block_until_ready``) under a span named exactly
-        like the dispatch kind and feed the profiler a sample keyed to
-        the log entry just written — the join ``dispatch_calibration``
-        later prices. The result value is identical either way (blocking
-        on it early cannot change its bits)."""
+        with telemetry enabled, enqueue it under the span
+        ``engine.dispatch.<kind>`` whose arguments (``span_args``, given
+        by callers only when telemetry is on) are the dispatch's work:
+        ``rows``, ``keys`` (:func:`attended_keys`), ``new_tokens`` (KV
+        positions written) and ``rid`` where one request owns it. The
+        span covers the enqueue only; the device time is read from the
+        profiler trace."""
         self._log_dispatch(kind, *args)
         tel = self.telemetry
         if not tel.enabled:
             return fn(params, *args)
-        idx = len(self.dispatch_log) - 1
-        with tel.span(kind, cat="dispatch", tid=self.tel_label,
-                      now_fn=self._vnow):
-            t0 = time.perf_counter()
-            out = jax.block_until_ready(fn(params, *args))
-            dt = time.perf_counter() - t0
-        tel.profiler.record(self.tel_label, idx, kind, dt)
         tel.counter("engine_dispatches_total", engine=self.tel_label,
                     kind=kind, kv=self.kv.name).inc()
-        tel.histogram("engine_dispatch_wall_s", engine=self.tel_label,
-                      kind=kind, kv=self.kv.name).observe(dt)
-        return out
+        with tel.span(f"engine.dispatch.{kind}", cat="dispatch",
+                      tid=self.tel_label, now_fn=self._vnow, **span_args):
+            return fn(params, *args)
+
+    def _observe_itl(self, rows, n_tokens) -> None:
+        """Telemetry: one ``engine_itl_s`` sample per inter-token gap of
+        each slot in ``rows``, from the slot's last-token stamp; tokens
+        committed together arrive together (gaps of 0 after the
+        first)."""
+        now = self._now()
+        hists = {}
+        for i, n in zip(rows, n_tokens):
+            req = self.slot_req[i]
+            key = (req.tenant or "default", str(req.priority))
+            h = hists.get(key)
+            if h is None:
+                h = hists[key] = self.telemetry.histogram(
+                    "engine_itl_s", engine=self.tel_label, tenant=key[0],
+                    priority=key[1])
+            h.observe(max(0.0, now - float(self.slot_t_last[i])))
+            for _ in range(int(n) - 1):
+                h.observe(0.0)
+            self.slot_t_last[i] = now
 
     def _finish(self, req: Request):
         """Retire ``req`` into ``finished`` (all five finish sites
@@ -730,10 +765,6 @@ class ServingEngine:
         tel.histogram("engine_ttft_s", engine=self.tel_label,
                       tenant=tenant, priority=prio).observe(
                           max(0.0, req.ttft_s))
-        if len(req.output) > 1:
-            tel.histogram("engine_itl_s", engine=self.tel_label,
-                          tenant=tenant, priority=prio).observe(
-                              max(0.0, req.itl_s))
 
     def step(self):
         """One engine iteration, orchestrated by the scheduling policy:
@@ -742,8 +773,8 @@ class ServingEngine:
         exactly one jitted dispatch per step, plus at most one chunk
         dispatch while a prompt is streaming in."""
         self.step_index += 1
-        with self._span("step", step=self.step_index):
-            with self._span("admit"):
+        with self._span("engine.step", step_num=self.step_index):
+            with self._span("engine.schedule.admit"):
                 self.scheduler.admit(self)
             chunk_slot = self.scheduler.select_chunk(self)
             if chunk_slot is not None:
@@ -755,7 +786,7 @@ class ServingEngine:
                     self._spec_step(live)
                 else:
                     self._decode_step(live)
-            with self._span("retire"):
+            with self._span("engine.schedule.retire"):
                 self.scheduler.retire(self)
         tel = self.telemetry
         if tel.enabled:
@@ -769,25 +800,35 @@ class ServingEngine:
 
     def _decode_step(self, live):
         """The vanilla one-token-per-slot ragged decode dispatch."""
-        cache = self.kv.decode_view(self.slot_pos, live)
+        tel = self.telemetry
+        with self._span("engine.kv.decode_view", cat="kv"):
+            cache = self.kv.decode_view(self.slot_pos, live)
         args = (jnp.asarray(self.slot_tok), cache,
                 jnp.asarray(self.slot_pos), jnp.asarray(live))
+        work = (rows_work(self.slot_pos[live], 1,
+                          self.cfg.sliding_window)
+                if tel.enabled else {})
         logits, new_cache = self._dispatch(
-            "decode", self._decode_ragged, self.params, *args)
-        with self._span("kv_commit", cat="kv"):
+            "decode", self._decode_ragged, self.params, *args, **work)
+        with self._span("engine.kv.commit", cat="kv"):
             self.kv.commit(new_cache)
         self.decode_dispatches += 1
         self.decode_steps += 1
-        with self._span("sample"):
-            new = np.asarray(self._sample(
+        with self._span("engine.sample"):
+            new = self._sample(
                 logits, jnp.asarray(self.slot_seed),
-                jnp.asarray(self.slot_rid), jnp.asarray(self.slot_pos)))
-        for i in np.nonzero(live)[0]:
+                jnp.asarray(self.slot_rid), jnp.asarray(self.slot_pos))
+        with self._span("engine.readback"):
+            new = np.asarray(new)
+        rows = np.nonzero(live)[0]
+        for i in rows:
             req = self.slot_req[i]
             req.output.append(int(new[i]))
             self.slot_tok[i, 0] = int(new[i])
             self.slot_len[i] += 1
             self.slot_pos[i] += 1
+        if tel.enabled:
+            self._observe_itl(rows, [1] * len(rows))
 
     def _spec_step(self, live):
         """One speculative verify step: gamma draft proposals per live
@@ -853,10 +894,13 @@ class ServingEngine:
             cur = self.slot_tok.copy()
             for t in range(chain):
                 logits = self._draft_dispatch(cur, live)
-                nxt = np.asarray(self._sample(
-                    logits, jnp.asarray(self.slot_seed),
-                    jnp.asarray(self.slot_rid),
-                    jnp.asarray(self.draft_pos)))
+                with self._span("engine.sample"):
+                    nxt = self._sample(
+                        logits, jnp.asarray(self.slot_seed),
+                        jnp.asarray(self.slot_rid),
+                        jnp.asarray(self.draft_pos))
+                with self._span("engine.readback"):
+                    nxt = np.asarray(nxt)
                 cand[:, t] = nxt
                 cur = nxt[:, None].astype(np.int32)
                 self.draft_pos = self.draft_pos + live  # rebind (above)
@@ -871,24 +915,33 @@ class ServingEngine:
         a width-1 verify of the pending token alone."""
         # -- one target dispatch verifies the whole ragged batch
         toks = np.concatenate([self.slot_tok, cand], axis=1)  # (B, chain+1)
-        cache = self.kv.verify_view(self.slot_pos, live,
-                                    np.minimum(n_write, chain + 1))
+        tel = self.telemetry
+        with self._span("engine.kv.verify_view", cat="kv"):
+            cache = self.kv.verify_view(self.slot_pos, live,
+                                        np.minimum(n_write, chain + 1))
         args = (jnp.asarray(toks), cache,
                 jnp.asarray(self.slot_pos), jnp.asarray(live))
+        work = (rows_work(self.slot_pos[live], chain + 1,
+                          self.cfg.sliding_window)
+                if tel.enabled else {})
         logits, new_cache = self._dispatch(
-            "verify", self._verify_ragged, self.params, *args)
-        with self._span("kv_commit", cat="kv"):
+            "verify", self._verify_ragged, self.params, *args, **work)
+        with self._span("engine.kv.commit", cat="kv"):
             self.kv.commit(new_cache)
         self.decode_dispatches += 1
         self.decode_steps += 1
         self.verify_dispatches += 1
         self.spec_row_steps += int(live.sum())
-        with self._span("sample"):
-            greedy = np.asarray(self._sample(
+        with self._span("engine.sample"):
+            greedy = self._sample(
                 logits, jnp.asarray(self.slot_seed),
-                jnp.asarray(self.slot_rid), jnp.asarray(self.slot_pos)))
+                jnp.asarray(self.slot_rid), jnp.asarray(self.slot_pos))
+        with self._span("engine.readback"):
+            greedy = np.asarray(greedy)
         # -- host acceptance + commit/rollback
-        for i in np.nonzero(live)[0]:
+        rows = np.nonzero(live)[0]
+        counts = []
+        for i in rows:
             req = self.slot_req[i]
             a = 0
             while a < chain and cand[i, a] == greedy[i, a]:
@@ -900,6 +953,7 @@ class ServingEngine:
                 if tok == self.ecfg.eos_token:
                     break  # vanilla stops after emitting EOS
             n = len(committed)
+            counts.append(n)
             req.output.extend(committed)
             req.spec_accepted.append(n)
             self.spec_committed += n
@@ -913,15 +967,23 @@ class ServingEngine:
             # (it consumed ``chain`` of them this round)
             self.kv.commit_n(i, p + n)
             self.draft_pos[i] = p + min(chain, n)
+        if tel.enabled:
+            self._observe_itl(rows, counts)
 
     def _draft_dispatch(self, toks, live):
         """One ragged draft-model decode dispatch (chain/catch-up)."""
-        cache = self.draft_kv.decode_view(self.draft_pos, live)
+        with self._span("engine.kv.decode_view", cat="kv"):
+            cache = self.draft_kv.decode_view(self.draft_pos, live)
         args = (jnp.asarray(toks), cache,
                 jnp.asarray(self.draft_pos), jnp.asarray(live))
+        work = (rows_work(self.draft_pos[live], 1,
+                          self.draft_cfg.sliding_window)
+                if self.telemetry.enabled else {})
         logits, new_cache = self._dispatch(
-            "draft_decode", self._draft_decode, self.draft_params, *args)
-        self.draft_kv.commit(new_cache)
+            "draft_decode", self._draft_decode, self.draft_params, *args,
+            **work)
+        with self._span("engine.kv.commit", cat="kv"):
+            self.draft_kv.commit(new_cache)
         self.draft_dispatches += 1
         return logits
 
@@ -956,7 +1018,9 @@ class ServingEngine:
         """Shared admission front half: zero-budget insta-finish,
         truncation, cache capacity check. Returns ``(prompt, n_prompt,
         budget)`` when the request should proceed, ``True`` when it was
-        consumed without touching the slot, ``False`` to defer it."""
+        consumed without touching the slot, ``False`` to defer it.
+        Stamps ``t_admit``: the request has left the queue."""
+        req.t_admit = self._now()
         budget = self._budget(req)
         if budget <= 0:
             # explicit zero-token request: nothing to generate — never
@@ -1014,8 +1078,11 @@ class ServingEngine:
                 else jnp.float32)
         pre_args = (batch, jnp.asarray(n_prompt - 1, jnp.int32),
                     jnp.asarray(n_prompt, jnp.int32))
+        tel = self.telemetry
+        work = (rows_work([0], n_prompt, self.cfg.sliding_window,
+                          rid=req.rid) if tel.enabled else {})
         logits, rows = self._dispatch(
-            "prefill", self._prefill_one, self.params, *pre_args)
+            "prefill", self._prefill_one, self.params, *pre_args, **work)
         self.prefills += 1
         self.admission_log.append(req.rid)
         req.prefill_chunks = 1
@@ -1028,22 +1095,28 @@ class ServingEngine:
             req.t_done = self._now()
             self._finish(req)
             return True
-        with self._span("kv_splice", cat="kv"):
+        with self._span("engine.kv.splice", cat="kv", rid=req.rid):
             self.kv.splice(rows, slot, n_prompt, budget,
                            prompt=prompt if self._prefix_on else None)
         if self._prefix_on:
             # publish the prompt's full blocks as shared (a cold miss:
             # the match above was empty) — the next request with this
             # prefix splices them instead of re-prefilling
-            self.kv.register_prefix(slot, prompt, n_prompt)
+            with self._span("engine.kv.register_prefix", cat="kv",
+                            rid=req.rid):
+                self.kv.register_prefix(slot, prompt, n_prompt)
         if self.draft_kv is not None:
             # speculative: the draft shadows the committed sequence —
             # prefill its cache over the same (bucketed) batch so the
             # chain can propose from position n_prompt immediately
+            work = (rows_work([0], n_prompt,
+                              self.draft_cfg.sliding_window,
+                              rid=req.rid) if tel.enabled else {})
             _, drows = self._dispatch(
                 "draft_prefill", self._draft_prefill, self.draft_params,
-                *pre_args)
-            self.draft_kv.splice(drows, slot, n_prompt, budget)
+                *pre_args, **work)
+            with self._span("engine.kv.splice", cat="kv", rid=req.rid):
+                self.draft_kv.splice(drows, slot, n_prompt, budget)
             self.draft_dispatches += 1
             self.draft_pos[slot] = n_prompt
         self._bind_decode(slot, req, seed, tok, n_prompt)
@@ -1063,22 +1136,27 @@ class ServingEngine:
         follows from determinism of the prompt KV: absolute-position
         RoPE + the same tokens produce the same blocks, so attending
         cached blocks equals re-computing them."""
-        h = self.kv.splice_prefix(slot, prompt, n_prompt, budget)
+        with self._span("engine.kv.splice_prefix", cat="kv", rid=req.rid):
+            h = self.kv.splice_prefix(slot, prompt, n_prompt, budget)
         n_suf = n_prompt - h
         nb = self._bucket_len(n_suf)
         toks = np.zeros(nb, np.int32)
         toks[:n_suf] = prompt[h:]
         batch = {"tokens": jnp.asarray(toks[None, :])}
-        view = self.kv.chunk_view(slot)
+        with self._span("engine.kv.chunk_view", cat="kv", rid=req.rid):
+            view = self.kv.chunk_view(slot)
         fn = self._chunk_fns[view["kind"]]
         sel = (jnp.asarray(view["slot"], jnp.int32)
                if view["kind"] == "contiguous" else view["table"])
         args = (batch, view["k"], view["v"], sel,
                 jnp.asarray(h, jnp.int32),
                 jnp.asarray(n_suf - 1, jnp.int32))
+        work = (rows_work([h], n_suf, self.cfg.sliding_window,
+                          rid=req.rid)
+                if self.telemetry.enabled else {})
         logits, ks, vs = self._dispatch(
-            f"chunk_{view['kind']}", fn, self.params, *args)
-        with self._span("kv_splice", cat="kv"):
+            f"chunk_{view['kind']}", fn, self.params, *args, **work)
+        with self._span("engine.kv.splice", cat="kv", rid=req.rid):
             self.kv.splice_partial(ks, vs, slot, h, n_suf)
         self.prefill_chunk_dispatches += 1
         self.admission_log.append(req.rid)
@@ -1094,7 +1172,8 @@ class ServingEngine:
             self._finish(req)
             self.kv.free(slot)
             return True
-        self.kv.register_prefix(slot, prompt, n_prompt)
+        with self._span("engine.kv.register_prefix", cat="kv", rid=req.rid):
+            self.kv.register_prefix(slot, prompt, n_prompt)
         self._bind_decode(slot, req, seed, tok, n_prompt)
         return True
 
@@ -1116,7 +1195,9 @@ class ServingEngine:
             # suffix); starting the chunk walk at ``done = h`` makes
             # _run_chunk stream in exactly ``prompt[h:]`` at the
             # matched history offset, unchanged
-            h = self.kv.splice_prefix(slot, prompt, n_prompt, budget)
+            with self._span("engine.kv.splice_prefix", cat="kv",
+                            rid=req.rid):
+                h = self.kv.splice_prefix(slot, prompt, n_prompt, budget)
         else:
             self.kv.reserve(slot, n_prompt, budget)
             h = 0
@@ -1154,16 +1235,20 @@ class ServingEngine:
         # chunk — chunk-local index of global position p is p - st.done
         # (only meaningful on the final chunk; 0 otherwise)
         logit_idx = st.n_prompt - 1 - st.done if final else 0
-        view = self.kv.chunk_view(slot)
+        with self._span("engine.kv.chunk_view", cat="kv", rid=req.rid):
+            view = self.kv.chunk_view(slot)
         fn = self._chunk_fns[view["kind"]]
         sel = (jnp.asarray(view["slot"], jnp.int32)
                if view["kind"] == "contiguous" else view["table"])
         args = (batch, view["k"], view["v"], sel,
                 jnp.asarray(st.done, jnp.int32),
                 jnp.asarray(logit_idx, jnp.int32))
+        work = (rows_work([st.done], n_valid, self.cfg.sliding_window,
+                          rid=req.rid)
+                if self.telemetry.enabled else {})
         logits, ks, vs = self._dispatch(
-            f"chunk_{view['kind']}", fn, self.params, *args)
-        with self._span("kv_splice", cat="kv"):
+            f"chunk_{view['kind']}", fn, self.params, *args, **work)
+        with self._span("engine.kv.splice", cat="kv", rid=req.rid):
             self.kv.splice_partial(ks, vs, slot, st.done, n_valid)
         self.prefill_chunk_dispatches += 1
         req.prefill_chunks += 1
@@ -1182,7 +1267,9 @@ class ServingEngine:
         if self._prefix_on:
             # the prompt's KV is fully resident now — publish its full
             # blocks (hash hits on already-shared blocks are skipped)
-            self.kv.register_prefix(slot, st.prompt, st.n_prompt)
+            with self._span("engine.kv.register_prefix", cat="kv",
+                            rid=req.rid):
+                self.kv.register_prefix(slot, st.prompt, st.n_prompt)
         self._bind_decode(slot, req, st.seed, tok, st.n_prompt)
 
     def _sample_first(self, req: Request, seed: int, logits,
@@ -1190,11 +1277,13 @@ class ServingEngine:
         """Sample the prompt's first token from prefill logits; stamps
         ``t_first`` — TTFT is measured to here, never to an
         intermediate chunk."""
-        with self._span("sample"):
-            tok = int(np.asarray(self._sample(
+        with self._span("engine.sample", rid=req.rid):
+            tok = self._sample(
                 logits, jnp.asarray([seed], jnp.int32),
                 jnp.asarray([req.rid], jnp.int32),
-                jnp.asarray([n_prompt - 1], jnp.int32)))[0])
+                jnp.asarray([n_prompt - 1], jnp.int32))
+        with self._span("engine.readback", rid=req.rid):
+            tok = int(np.asarray(tok)[0])
         req.t_first = self._now()
         req.output.append(tok)
         return tok
@@ -1209,6 +1298,8 @@ class ServingEngine:
         self.slot_rid[slot] = req.rid
         self.slot_seed[slot] = seed
         self.slot_nprompt[slot] = n_prompt
+        if self.telemetry.enabled:
+            self.slot_t_last[slot] = req.t_first
 
     def _retire_slot(self, i: int):
         """Release slot ``i`` (scheduler-decided retirement)."""
@@ -1233,7 +1324,7 @@ class ServingEngine:
         # req.prompt may have been truncated at admission): the importer
         # re-matches it against its own index and aliases whatever it
         # already holds instead of copying the prefix in
-        with self._span("kv_export", cat="kv"):
+        with self._span("engine.kv.export", cat="kv", rid=req.rid):
             pkt = SlotPacket(
                 req=req, seed=int(self.slot_seed[slot]),
                 tok=int(self.slot_tok[slot, 0]),
@@ -1245,7 +1336,8 @@ class ServingEngine:
                     slot, int(self.slot_pos[slot]),
                     prompt=(req.prompt[:n_prompt]
                             if self._prefix_on else None),
-                    n_prompt=n_prompt if self._prefix_on else None))
+                    n_prompt=n_prompt if self._prefix_on else None),
+                t_last=float(self.slot_t_last[slot]))
         self.slot_req[slot] = None
         self.slot_len[slot] = 0
         self.kv.free(slot)
@@ -1255,7 +1347,7 @@ class ServingEngine:
         """Land a packet in free slot ``slot`` and rebind the stream
         (inverse of :meth:`_pack_slot`; the import re-runs the
         reservation math, so callers must check ``can_admit`` first)."""
-        with self._span("kv_import", cat="kv"):
+        with self._span("engine.kv.import", cat="kv", rid=pkt.req.rid):
             self.kv.import_slot(pkt.kv, slot, pkt.n_prompt, pkt.budget)
         self.slot_req[slot] = pkt.req
         self.slot_len[slot] = pkt.gen_len
@@ -1264,6 +1356,7 @@ class ServingEngine:
         self.slot_rid[slot] = pkt.req.rid
         self.slot_seed[slot] = pkt.seed
         self.slot_nprompt[slot] = pkt.n_prompt
+        self.slot_t_last[slot] = pkt.t_last
 
     def preempt_slot(self, slot: int) -> SlotPacket:
         """Evict slot ``slot``'s live stream to the waiting queue:
@@ -1285,7 +1378,7 @@ class ServingEngine:
                 "preemption is unsupported under speculative decoding: "
                 "the draft's shadow cache is not part of the export "
                 "packet and cannot resume")
-        with self._span("preempt", rid=req.rid):
+        with self._span("engine.preempt", rid=req.rid):
             pkt = self._pack_slot(slot)
         self.preempted_packets[req.rid] = pkt
         req.preemptions += 1

@@ -430,11 +430,12 @@ def splice_rows(cache: dict, rows: dict, slot):
     cross state) in row ``slot`` of the dense cache. ``slot`` is traced:
     one compile total."""
     axes = MD.cache_batch_axes(cache)
-    return {name: (leaf if axes[name] is None
-                   else jax.lax.dynamic_update_slice_in_dim(
-                       leaf, rows[name].astype(leaf.dtype), slot,
-                       axes[name]))
-            for name, leaf in cache.items()}
+    with jax.named_scope("kv_write"):
+        return {name: (leaf if axes[name] is None
+                       else jax.lax.dynamic_update_slice_in_dim(
+                           leaf, rows[name].astype(leaf.dtype), slot,
+                           axes[name]))
+                for name, leaf in cache.items()}
 
 
 @partial(jax.jit, donate_argnums=(0, 1))

@@ -1,31 +1,34 @@
-"""Serving telemetry: span tracing, metrics, and a dispatch profiler.
+"""Serving telemetry: span tracing and metrics.
 
-Three zero-dependency instruments that close the loop on the jaxpr cost
-model (``core/costmodel.py``):
+Two zero-dependency instruments behind one :class:`Telemetry` facade
+that both ``ServingEngine`` and ``ClusterEngine`` accept (shared across
+workers):
 
-* :class:`SpanTracer` — nested spans around every engine phase (admit,
-  prefill chunk, decode dispatch, draft/verify, sampling, KV
-  splice/commit/export/import, preemption, migration, autoscale) with
-  both wall-clock (``time.perf_counter``) and virtual-clock (the
-  engine's ``now_s``) timestamps, exportable as Chrome/Perfetto
-  trace-event JSON (``chrome://tracing`` / https://ui.perfetto.dev).
+* :class:`SpanTracer` — nested spans around every engine phase (step,
+  scheduler admit/retire, each dispatch's enqueue, sampling, readback,
+  KV views/commits/splices/export/import, preemption, and the cluster's
+  routing, handoff, migration and autoscale) with both wall-clock
+  (``time.perf_counter``) and virtual-clock (the engine's ``now_s``)
+  timestamps, exportable as Chrome/Perfetto trace-event JSON
+  (``chrome://tracing`` / https://ui.perfetto.dev).
 * :class:`MetricsRegistry` — labeled counters / gauges / log-bucketed
   histograms with a snapshot/delta API and Prometheus text exposition.
   Bucketing is a pure function of the sample value, so merging two
   snapshots commutes with merging the underlying streams.
-* :class:`DispatchProfiler` — per-dispatch ``block_until_ready`` wall
-  time keyed to the exact ``dispatch_log`` entry it measured, so
-  :func:`dispatch_calibration` can join measured seconds against the
-  pricer's traced FLOPs/DMA bytes and report achieved FLOP/s, achieved
-  bandwidth, arithmetic intensity, and a model-error ratio per dispatch
-  kind.
 
-Everything hangs off a single :class:`Telemetry` facade that both
-``ServingEngine`` and ``ClusterEngine`` accept (shared across workers).
+Enabled, every span is also a ``jax.profiler.TraceAnnotation`` of the
+same name whose arguments are the span's labels, so under a running
+``jax.profiler`` trace the engine's spans share a clock with the device
+operations (whose programs carry ``jax.named_scope`` layer names, see
+``models/model.py``). Span names start with ``engine.`` (``cluster.``
+for the cluster's own track). Nothing here blocks on the device: a
+dispatch span covers the enqueue only, and device time per dispatch is
+read from the profiler trace.
+
 Disabled (the default, via :data:`NULL_TELEMETRY`) every hook
-short-circuits to a no-op singleton: no spans, no metric mutations, no
-``block_until_ready`` — the engine's one-dispatch-per-step invariant
-and bitwise outputs are untouched either way.
+short-circuits to a no-op singleton: no spans, no annotations, no
+metric mutations — the engine's one-dispatch-per-step invariant and
+bitwise outputs are untouched either way.
 """
 from __future__ import annotations
 
@@ -33,7 +36,9 @@ import json
 import math
 import time
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
+
+import jax
 
 
 # ---------------------------------------------------------------------------
@@ -518,137 +523,29 @@ def merge_snapshots(a: Dict[str, Dict[str, Any]],
 
 
 # ---------------------------------------------------------------------------
-# dispatch profiler
-# ---------------------------------------------------------------------------
-
-@dataclass
-class DispatchSample:
-    """Wall time of one jitted dispatch, keyed to its dispatch_log row."""
-
-    engine: str   # telemetry label of the engine that dispatched
-    index: int    # position in that engine's ``dispatch_log``
-    kind: str     # dispatch kind ("decode", "chunk_paged", ...)
-    wall_s: float
-
-
-class DispatchProfiler:
-    def __init__(self):
-        self.samples: List[DispatchSample] = []
-
-    def record(self, engine: str, index: int, kind: str, wall_s: float):
-        self.samples.append(DispatchSample(engine, index, kind, wall_s))
-
-
-def join_coverage(engine, telemetry: "Telemetry"
-                  ) -> Tuple[int, int]:
-    """(# dispatch_log entries with a profiler sample, # entries)."""
-    label = getattr(engine, "tel_label", "engine")
-    sampled = {s.index for s in telemetry.profiler.samples
-               if s.engine == label}
-    return len(sampled & set(range(len(engine.dispatch_log)))), \
-        len(engine.dispatch_log)
-
-
-# Generic host-CPU reference point used when no HardwareProfile is
-# given: the calibration table still reports finite ratios on the CI
-# runner; absolute values are only meaningful against a real profile.
-HOST_REF_OPS_PER_S = 1e11
-HOST_REF_BYTES_PER_S = 5e10
-
-
-def dispatch_calibration(engines, telemetry: "Telemetry",
-                         profile=None) -> Dict[str, Dict[str, float]]:
-    """Join measured dispatch wall times against traced FLOPs/bytes.
-
-    For every profiler sample, the dispatch-log entry it measured is
-    re-traced through ``core.costmodel.entry_tracer`` (the same join
-    the drift audit uses), and per dispatch kind we aggregate:
-
-    ``n``, ``wall_s``, ``flops``, ``bytes``, ``achieved_flops_per_s``,
-    ``achieved_bytes_per_s``, ``arithmetic_intensity``, ``predicted_s``
-    (roofline max(flops/peak_ops, bytes/peak_bw) per dispatch against
-    ``profile`` — a :class:`repro.core.profiles.HardwareProfile` — or
-    the generic host reference), and ``model_error_ratio`` =
-    wall_s / predicted_s. A finite ratio for every kind is the CI gate.
-    """
-    # costmodel imports serving.engine which imports this module —
-    # resolve the cycle by importing lazily at call time.
-    import jax
-
-    from repro.core import costmodel as CM
-    from repro.core import trace as T
-
-    if not isinstance(engines, (list, tuple)):
-        engines = [engines]
-    if profile is not None:
-        peak_ops = profile.ops_per_s
-        peak_bw = profile.mem_bw_gbs * 1e9
-    elif jax.default_backend() == "tpu":
-        raise ValueError(
-            "dispatch_calibration on a TPU needs an explicit profile: the "
-            "host reference peaks describe a CPU, and ratios against them "
-            "would be reported as if they described the chip")
-    else:
-        peak_ops, peak_bw = HOST_REF_OPS_PER_S, HOST_REF_BYTES_PER_S
-
-    by_label = {}
-    tracers = {}
-    for eng in engines:
-        label = getattr(eng, "tel_label", "engine")
-        by_label[label] = eng
-        tracers[label] = CM.entry_tracer(eng)
-
-    agg: Dict[str, Dict[str, float]] = {}
-    for s in telemetry.profiler.samples:
-        eng = by_label.get(s.engine)
-        if eng is None or s.index >= len(eng.dispatch_log):
-            continue
-        entry = eng.dispatch_log[s.index]
-        tot = T.totals(tracers[s.engine](entry))
-        row = agg.setdefault(s.kind, {
-            "n": 0, "wall_s": 0.0, "flops": 0.0, "bytes": 0.0,
-            "predicted_s": 0.0})
-        row["n"] += 1
-        row["wall_s"] += s.wall_s
-        row["flops"] += tot.flops
-        row["bytes"] += tot.bytes
-        row["predicted_s"] += max(tot.flops / peak_ops, tot.bytes / peak_bw)
-
-    for kind, row in agg.items():
-        wall = row["wall_s"]
-        row["achieved_flops_per_s"] = row["flops"] / wall if wall > 0 else 0.0
-        row["achieved_bytes_per_s"] = row["bytes"] / wall if wall > 0 else 0.0
-        row["arithmetic_intensity"] = (row["flops"] / row["bytes"]
-                                       if row["bytes"] > 0 else 0.0)
-        row["model_error_ratio"] = (wall / row["predicted_s"]
-                                    if row["predicted_s"] > 0
-                                    else float("nan"))
-    return agg
-
-
-def format_calibration(table: Dict[str, Dict[str, float]]) -> str:
-    """Human-readable achieved-vs-predicted table for one calibration."""
-    hdr = (f"{'kind':<16} {'n':>5} {'wall_ms':>9} {'GFLOP/s':>9} "
-           f"{'GB/s':>8} {'AI':>8} {'pred_ms':>9} {'meas/pred':>9}")
-    lines = [hdr, "-" * len(hdr)]
-    for kind in sorted(table):
-        r = table[kind]
-        lines.append(
-            f"{kind:<16} {int(r['n']):>5} {r['wall_s'] * 1e3:>9.3f} "
-            f"{r['achieved_flops_per_s'] / 1e9:>9.2f} "
-            f"{r['achieved_bytes_per_s'] / 1e9:>8.2f} "
-            f"{r['arithmetic_intensity']:>8.2f} "
-            f"{r['predicted_s'] * 1e3:>9.3f} "
-            f"{r['model_error_ratio']:>9.3f}")
-    return "\n".join(lines)
-
-
-# ---------------------------------------------------------------------------
 # facade
 # ---------------------------------------------------------------------------
 
+class _ProfiledSpan:
+    """A tracer span that is also a profiler annotation."""
+
+    __slots__ = ("span", "ann")
+
+    def __init__(self, span, ann):
+        self.span = span
+        self.ann = ann
+
+    def __enter__(self):
+        self.ann.__enter__()
+        return self.span.__enter__()
+
+    def __exit__(self, *exc):
+        self.span.__exit__(*exc)
+        return self.ann.__exit__(*exc)
+
+
 class Telemetry:
-    """Shared telemetry hub: span tracer + metrics + dispatch profiler.
+    """Shared telemetry hub: span tracer + metrics.
 
     Pass one instance to any number of engines/workers; every hook
     checks ``enabled`` first and returns a no-op singleton when off, so
@@ -659,17 +556,25 @@ class Telemetry:
         self.enabled = enabled
         self.tracer = SpanTracer()
         self.metrics = MetricsRegistry()
-        self.profiler = DispatchProfiler()
 
     # -- recording --------------------------------------------------------
 
     def span(self, name: str, cat: str = "phase", tid: str = "engine",
              now_fn: Optional[Callable[[], Optional[float]]] = None,
-             **labels):
+             step_num: Optional[int] = None, **labels):
+        """A span on track ``tid``, annotated in the profiler trace as
+        ``name`` with ``labels`` as its arguments; with ``step_num`` it
+        marks a step (``jax.profiler.StepTraceAnnotation``) and the
+        tracer records the number under ``step``."""
         if not self.enabled:
             return _NULL_CTX
-        return self.tracer.span(name, cat=cat, tid=tid, now_fn=now_fn,
-                                **labels)
+        if step_num is None:
+            ann = jax.profiler.TraceAnnotation(name, **labels)
+        else:
+            ann = jax.profiler.StepTraceAnnotation(name, step_num=step_num)
+            labels["step"] = step_num
+        return _ProfiledSpan(self.tracer.span(name, cat=cat, tid=tid,
+                                              now_fn=now_fn, **labels), ann)
 
     def counter(self, name: str, **labels):
         if not self.enabled:
@@ -689,10 +594,11 @@ class Telemetry:
     # -- aggregates -------------------------------------------------------
 
     def engine_aggregates(self, tid: str) -> Dict[str, Any]:
-        """Always-present summary fold-in for one engine label."""
+        """Always-present summary fold-in for one engine label: spans
+        recorded, wall time of its outermost spans, and dispatches
+        enqueued."""
         out = {"enabled": bool(self.enabled), "spans": 0,
-               "span_wall_s": 0.0, "dispatches": 0,
-               "dispatch_wall_s": 0.0}
+               "span_wall_s": 0.0, "dispatches": 0}
         if not self.enabled:
             return out
         for s in self.tracer.spans:
@@ -701,11 +607,8 @@ class Telemetry:
             out["spans"] += 1
             if s.depth == 0:
                 out["span_wall_s"] += s.wall_dur_s
-        for d in self.profiler.samples:
-            if d.engine != tid:
-                continue
-            out["dispatches"] += 1
-            out["dispatch_wall_s"] += d.wall_s
+            if s.cat == "dispatch":
+                out["dispatches"] += 1
         return out
 
 

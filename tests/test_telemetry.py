@@ -1,10 +1,11 @@
 """Serving telemetry layer: span tracer, metrics registry, and the
-measured-vs-predicted dispatch profiler (the loop-closer on the jaxpr
-cost model)."""
+profiler annotations that put the engine's spans on the device trace's
+clock."""
 from __future__ import annotations
 
+import ast
 import json
-import math
+import pathlib
 
 import jax
 import numpy as np
@@ -13,8 +14,8 @@ import pytest
 from repro.configs import registry
 from repro.models import model as MD
 from repro.serving import (EngineConfig, ServingEngine, Telemetry,
-                           dispatch_calibration, join_coverage,
                            merge_snapshots, validate_trace_events)
+from repro.serving.engine import attended_keys
 from repro.serving.telemetry import (MetricsRegistry, SpanTracer,
                                      bucket_index, bucket_upper)
 from repro.serving.workload import SLO, TenantSpec, make_trace, replay
@@ -84,8 +85,10 @@ def test_span_nesting_depths(setup):
     by_name = {}
     for s in tel.tracer.spans:
         by_name.setdefault(s.name, []).append(s)
-    assert all(s.depth == 0 for s in by_name["step"])
-    for name in ("admit", "prefill", "decode", "kv_commit", "sample"):
+    assert all(s.depth == 0 for s in by_name["engine.step"])
+    for name in ("engine.schedule.admit", "engine.dispatch.prefill",
+                 "engine.dispatch.decode", "engine.kv.commit",
+                 "engine.sample", "engine.readback"):
         assert name in by_name, f"no {name!r} spans recorded"
         assert all(s.depth >= 1 for s in by_name[name]), name
     # every span closed, wall-ordered within its track
@@ -102,7 +105,8 @@ def test_perfetto_export_schema_valid(setup):
         json.dumps(obj)   # artifact must serialize as-is
     names = {e["name"] for e in tel.tracer.trace_events()["traceEvents"]
              if e.get("ph") == "X"}
-    assert {"step", "prefill", "decode"} <= names
+    assert {"engine.step", "engine.dispatch.prefill",
+            "engine.dispatch.decode"} <= names
     with pytest.raises(ValueError, match="clock"):
         tel.tracer.trace_events(clock="lamport")
 
@@ -199,7 +203,7 @@ def test_registry_counters_gauges_delta_and_prometheus():
 
 
 # ---------------------------------------------------------------------------
-# dispatch profiler: 100% join + finite calibration
+# dispatch spans: every dispatch spanned, with the work it carries
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("kv_cache,scheduler", [
@@ -210,46 +214,172 @@ def test_registry_counters_gauges_delta_and_prometheus():
     ("contiguous", "speculative"),
     ("paged", "speculative"),
 ])
-def test_profiler_joins_every_dispatch(setup, kv_cache, scheduler):
-    """Every dispatch_log entry gets a measured wall-time sample, every
-    logged kind gets a span, and the calibration joining both against
-    the traced FLOPs/bytes is finite for every kind."""
+def test_every_dispatch_has_its_span(setup, kv_cache, scheduler,
+                                     monkeypatch):
+    """Every ``dispatch_log`` entry has an ``engine.dispatch.<kind>``
+    span carrying ``rows``, ``keys`` and ``new_tokens``; a decode
+    dispatch's ``keys`` is the sum over its live rows of
+    ``min(pos + 1, window)``, counted here from the positions the
+    engine holds when it dispatches."""
     cfg, params = setup
     tel = Telemetry()
+    want = []
+    decode = ServingEngine._decode_step
+
+    def watched(self, live):
+        want.append(sum(int(p) + 1 for p in self.slot_pos[live]))
+        return decode(self, live)
+    monkeypatch.setattr(ServingEngine, "_decode_step", watched)
     eng = _drive(params, cfg, tel, seed=4, label=f"{kv_cache}-{scheduler}",
                  kv_cache=kv_cache, scheduler=scheduler,
                  chunk_tokens=16, spec_gamma=2)
-    joined, total = join_coverage(eng, tel)
-    assert total > 0 and joined == total
-    logged = {e["kind"] for e in eng.dispatch_log}
-    spanned = {s.name for s in tel.tracer.spans if s.cat == "dispatch"}
-    assert logged <= spanned
-    calib = dispatch_calibration(eng, tel)
-    assert set(calib) == logged
-    for kind, row in calib.items():
-        assert row["n"] >= 1, kind
-        assert row["predicted_s"] > 0, kind
-        assert math.isfinite(row["model_error_ratio"]), kind
-        assert row["achieved_flops_per_s"] >= 0, kind
+    spans = [s for s in tel.tracer.spans if s.cat == "dispatch"]
+    assert [s.name for s in spans] == [
+        f"engine.dispatch.{e['kind']}" for e in eng.dispatch_log]
+    for s in spans:
+        assert {"rows", "keys", "new_tokens"} <= set(s.labels), s.name
+        assert s.labels["rows"] >= 1 and s.labels["new_tokens"] >= 1
+    got = [s.labels["keys"] for s in spans
+           if s.name == "engine.dispatch.decode"]
+    assert got == want
+    if scheduler == "speculative":
+        assert got == [] and any(
+            s.name == "engine.dispatch.verify" for s in spans)
 
 
-def test_calibration_respects_hardware_profile(setup, monkeypatch):
-    """predicted_s scales with the profile roofline: a faster profile
-    predicts less time, so the measured/predicted ratio grows. On a TPU
-    the host reference peaks are refused: a profile must be given."""
-    from repro.core import profiles as HW
+def test_attended_keys_by_hand():
+    # decode rows at positions 3 and 9: 4 + 10 keys; a window of 5
+    # caps the second at 5
+    assert attended_keys([3, 9], 1, None) == 14
+    assert attended_keys([3, 9], 1, 5) == 9
+    # a 4-token chunk after 2 cached positions: 3 + 4 + 5 + 6 keys;
+    # under a window of 4: 3 + 4 + 4 + 4
+    assert attended_keys([2], 4, None) == 18
+    assert attended_keys([2], 4, 4) == 15
+    # a row past the window sees exactly the window for every token
+    assert attended_keys([10], 3, 4) == 12
+    assert attended_keys([], 1, None) == 0
+
+
+def test_dispatch_never_blocks_on_the_device(setup, monkeypatch):
+    """With telemetry on, ``_dispatch`` only enqueues: it never waits
+    for the dispatch's result."""
+    cfg, params = setup
+
+    def refuse(*a, **k):
+        raise AssertionError("block_until_ready on the dispatch path")
+    monkeypatch.setattr(jax, "block_until_ready", refuse)
+    tel = Telemetry()
+    eng = _drive(params, cfg, tel, seed=5, kv_cache="paged",
+                 scheduler="chunked", chunk_tokens=16)
+    assert len(eng.finished) == 3 and tel.tracer.spans
+
+
+def test_disabled_telemetry_builds_no_annotation(setup, monkeypatch):
+    """Off, no span reaches the profiler: no ``TraceAnnotation`` (or
+    step annotation) is built; on, each span builds one."""
+    cfg, params = setup
+    built = []
+
+    class Counting:
+        def __init__(self, name, **kw):
+            built.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counting)
+    monkeypatch.setattr(jax.profiler, "StepTraceAnnotation", Counting)
+    _drive(params, cfg, Telemetry(enabled=False), seed=6)
+    _drive(params, cfg, None, seed=6)
+    assert built == []
+    tel = Telemetry()
+    _drive(params, cfg, tel, seed=6)
+    assert built == [s.name for s in sorted(tel.tracer.spans,
+                                            key=lambda s: s.index)]
+
+
+# the spans a benchmark harness may write from outside the engine
+HARNESS_SPANS = {"engine.admit", "engine.retire", "engine.prefill",
+                 "engine.chunk", "engine.decode"}
+SERVING = pathlib.Path(__file__).resolve().parents[1] / "src" / "repro" / \
+    "serving"
+
+
+def _span_names(path):
+    """Literal names of every ``_span(...)`` call in a module (a name
+    built at run time is an f-string: its constant prefix)."""
+    out = set()
+    for node in ast.walk(ast.parse(path.read_text())):
+        if not (isinstance(node, ast.Call)
+                and isinstance(node.func, ast.Attribute)
+                and node.func.attr in ("_span", "span") and node.args):
+            continue
+        a = node.args[0]
+        if isinstance(a, ast.Constant):
+            out.add(a.value)
+        elif isinstance(a, ast.JoinedStr):
+            out.add(a.values[0].value + "*")
+        elif isinstance(a, ast.IfExp):
+            out |= {a.body.value, a.orelse.value}
+    return out
+
+
+def test_profiler_names_are_unique_and_prefixed(setup):
+    """Each profiler name belongs to one kind of span (one category)
+    and starts with ``engine.`` (``cluster.`` on the cluster's own
+    track); none is a name a harness writes around the engine's
+    methods."""
+    engine = _span_names(SERVING / "engine.py")
+    cluster = _span_names(SERVING / "cluster.py")
+    assert engine and all(n.startswith("engine.") for n in engine)
+    assert cluster and all(n.startswith("cluster.") for n in cluster)
+    assert not (engine | cluster) & HARNESS_SPANS
     cfg, params = setup
     tel = Telemetry()
-    eng = _drive(params, cfg, tel, seed=5)
-    host = dispatch_calibration(eng, tel)
-    pim = dispatch_calibration(eng, tel, profile=HW.PIM_AI_CHIP)
-    for kind in host:
-        assert pim[kind]["predicted_s"] != host[kind]["predicted_s"]
-        assert math.isfinite(pim[kind]["model_error_ratio"])
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    with pytest.raises(ValueError, match="explicit profile"):
-        dispatch_calibration(eng, tel)
-    assert dispatch_calibration(eng, tel, profile=HW.PIM_AI_CHIP) == pim
+    for sched in ("blocking", "chunked", "speculative"):
+        _drive(params, cfg, tel, seed=7, label=sched, scheduler=sched,
+               kv_cache="paged", chunk_tokens=16, spec_gamma=2)
+    cats = {}
+    for s in tel.tracer.spans:
+        assert s.name.startswith("engine.") and s.name not in \
+            HARNESS_SPANS, s.name
+        cats.setdefault(s.name, set()).add(s.cat)
+    assert all(len(c) == 1 for c in cats.values()), cats
+
+
+@pytest.mark.parametrize("scheduler", ["blocking", "chunked",
+                                       "speculative"])
+def test_admission_stamp_orders_submit_and_first_token(setup, scheduler):
+    """``t_submit <= t_admit <= t_first`` for every finished request,
+    telemetry off (the stamp is always taken), with more requests than
+    slots so some wait in the queue."""
+    cfg, params = setup
+    eng = ServingEngine(params, cfg, EngineConfig(
+        max_batch=2, max_seq_len=64, max_new_tokens=4,
+        scheduler=scheduler, chunk_tokens=16, spec_gamma=2))
+    rng = np.random.default_rng(12)
+    for n in (6, 11, 17, 9, 13):
+        eng.submit(rng.integers(0, cfg.vocab_size, size=n))
+    eng.run()
+    assert len(eng.finished) == 5
+    for r in eng.finished:
+        assert r.t_submit <= r.t_admit <= r.t_first, r.rid
+    queued = max(r.t_admit - r.t_submit for r in eng.finished)
+    assert queued > 0
+
+
+def test_itl_histogram_observes_every_gap(setup):
+    """``engine_itl_s`` gets one sample per gap between consecutive
+    tokens of every request, not one mean per request."""
+    cfg, params = setup
+    tel = Telemetry()
+    eng = _drive(params, cfg, tel, seed=13)
+    snap = tel.metrics.snapshot()
+    h = snap['engine_itl_s{engine="engine",priority="0",tenant="default"}']
+    assert h["count"] == sum(len(r.output) - 1 for r in eng.finished)
 
 
 # ---------------------------------------------------------------------------
@@ -262,10 +392,9 @@ def test_disabled_telemetry_records_nothing_and_is_bitwise(setup):
     eng_off = _drive(params, cfg, off, seed=6, kv_cache="paged")
     assert off.tracer.spans == []
     assert off.metrics.snapshot() == {}
-    assert off.profiler.samples == []
     assert off.engine_aggregates("engine") == {
         "enabled": False, "spans": 0, "span_wall_s": 0.0,
-        "dispatches": 0, "dispatch_wall_s": 0.0}
+        "dispatches": 0}
 
     on = Telemetry()
     eng_on = _drive(params, cfg, on, seed=6, kv_cache="paged")
@@ -283,23 +412,20 @@ def test_summary_folds_in_telemetry_aggregates(setup):
     s = eng.summary()["telemetry"]
     assert s["enabled"] and s["spans"] > 0
     assert s["dispatches"] == len(eng.dispatch_log)
-    assert s["dispatch_wall_s"] > 0
     # depth-0 wall time only: no double counting of nested spans
     assert s["span_wall_s"] <= sum(
         sp.wall_dur_s for sp in tel.tracer.spans if sp.tid == "agg") + 1e-9
 
 
 def test_shared_hub_separates_engine_tracks(setup):
-    """One Telemetry across two engines: spans/samples key by label, and
-    join/calibration only consume the matching engine's samples."""
+    """One Telemetry across two engines: spans key by label, and each
+    engine's aggregates count only its own dispatches."""
     cfg, params = setup
     tel = Telemetry()
     a = _drive(params, cfg, tel, seed=8, label="a")
     b = _drive(params, cfg, tel, seed=9, label="b", kv_cache="paged")
-    assert join_coverage(a, tel) == (len(a.dispatch_log),
-                                     len(a.dispatch_log))
-    assert join_coverage(b, tel) == (len(b.dispatch_log),
-                                     len(b.dispatch_log))
+    assert tel.engine_aggregates("a")["dispatches"] == len(a.dispatch_log)
+    assert tel.engine_aggregates("b")["dispatches"] == len(b.dispatch_log)
     tids = {s.tid for s in tel.tracer.spans}
     assert {"a", "b"} <= tids
 
