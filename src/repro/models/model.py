@@ -833,8 +833,14 @@ def write_pool_positions(pool, kv, blk, off):
     pool (L, NB, bs, H, Dh): token (r, t) lands in block ``blk[r, t]``
     at row ``off[r, t]``; entries with ``blk >= NB`` (the sentinel)
     leave the pool unchanged. A loop of read-modify-write updates, one
-    per token, for the same layout reason as :func:`write_window` (the
-    pool keeps its block axis minor)."""
+    per token: a scatter would make the compiler copy the pool. The
+    decode and verify writes (a token or a few in each of many rows)
+    come here; a prefill chunk goes to :func:`write_pool_chunk`.
+
+    Where Dh is not a multiple of the TPU's 128 lanes (phi3's 96) the
+    compiler makes the block axis the lane axis of the pool's tiles
+    (``{1,4,3,2,0:T(8,128)}``), so each token's update rewrites one lane
+    of every tile of its 128-block group."""
     nb = pool.shape[1]
     r, s = kv.shape[1], kv.shape[2]
     kv = kv.astype(pool.dtype)
@@ -852,13 +858,99 @@ def write_pool_positions(pool, kv, blk, off):
     return jax.lax.fori_loop(0, r * s, body, pool)
 
 
+POOL_LANES = 128    # the TPU's lanes: blocks in one lane group of a pool
+
+
+def lane_groups(num_blocks):
+    """``(GW, starts)``: a pool's blocks in groups of ``GW = 128`` (all of
+    a smaller pool), group ``g`` the GW blocks from ``starts[g]``, the
+    last group pulled back to end at the pool's end. Block ``b`` belongs
+    to group ``b // GW``."""
+    gw = min(POOL_LANES, num_blocks)
+    return gw, [min(g * gw, num_blocks - gw)
+                for g in range(-(-num_blocks // gw))]
+
+
+@jax.named_scope("kv_write")
+def write_pool_chunk(pool, kv, lanes, shift, n_valid):
+    """Write one row's prefill chunk ``kv`` (L, 1, S, H, Dh) into the
+    block pool (L, NB, bs, H, Dh), one lane group of blocks at a time.
+
+    Token ``t`` sits at chunk position ``shift + t``: chunk block
+    ``(shift + t) // bs``, row ``(shift + t) % bs``. ``lanes[g, j]`` is
+    the chunk block that lands in block ``j`` of lane group ``g``
+    (:func:`lane_groups`), or -1 where that block keeps its contents;
+    a group with no chunk block is not touched. Tokens ``t >= n_valid``
+    (a pad tail) and the rows of a chunk block before ``shift`` or past
+    the last token keep theirs too.
+
+    On the TPU the pool's block axis is the lane axis of its tiles
+    (:func:`write_pool_positions`), so a group of 128 blocks of one
+    layer is a whole number of tiles: it is read once, merged with every
+    token of the chunk that falls in it in one fused select, and written
+    back with one ``dynamic_update_slice`` at a start fixed at compile
+    time (on a v5e, a start the compiler could not prove a multiple of
+    128 made each layer's write four to five times slower), where a
+    per-token loop rewrites the same tiles once per token. One layer at a time keeps the merged group small enough for
+    the compiler to hold on chip (phi3: 12.6 MB). The chunk's blocks
+    move into their lanes by a one-hot matmul over the bytes of the
+    values (a gather along the lane axis makes the compiler relayout the
+    group): every product is a byte times 0, 1 or 256 and at most two
+    terms of a sum are nonzero, so each 16-bit word comes out exact,
+    whatever its bits."""
+    n_layers, _, s, h, dh = kv.shape
+    bs = pool.shape[2]
+    gw, starts = lane_groups(pool.shape[1])
+    nq = -(-(s + bs - 1) // bs)            # blocks a chunk can touch
+    kv = kv.astype(pool.dtype)
+    zeros = partial(jnp.zeros, dtype=kv.dtype)
+    rows = jnp.concatenate([zeros((n_layers, bs, h, dh)), kv[:, 0],
+                            zeros((n_layers, nq * bs - s, h, dh))], axis=1)
+    rows = jax.lax.dynamic_slice_in_dim(rows, bs - shift, nq * bs, axis=1)
+    # (L, bs*H*Dh*w, 2*nq): each 16-bit word's high and low byte for
+    # every chunk block, the blocks minor, so one layer is the left
+    # operand of a plain (rows x 2*nq) @ (2*nq x GW) matmul
+    words = jax.lax.bitcast_convert_type(rows, jnp.uint16).reshape(
+        n_layers, nq, -1).transpose(0, 2, 1)
+    planes = jnp.concatenate([words >> 8, words & 0xFF], axis=-1).astype(
+        jnp.bfloat16)
+
+    def merge(lane_q, start):
+        hit = (lane_q[None, :] == jnp.arange(nq)[:, None]).astype(
+            jnp.bfloat16)
+        hit = jnp.concatenate([hit * 256, hit])
+        t = lane_q[:, None] * bs + jnp.arange(bs)[None, :] - shift
+        keep = (lane_q[:, None] >= 0) & (t >= 0) & (t < n_valid)
+
+        def layer(i, pool):
+            moved = jnp.dot(planes[i], hit,
+                            preferred_element_type=jnp.float32)
+            out = jnp.moveaxis(moved.astype(jnp.uint16).reshape(
+                bs, h, dh, -1, gw), -1, 0)
+            if pool.dtype.itemsize == 2:
+                out = out[..., 0]
+            new = jax.lax.bitcast_convert_type(out, pool.dtype)[None]
+            idx = (i, start, 0, 0, 0)
+            old = jax.lax.dynamic_slice(pool, idx, new.shape)
+            return jax.lax.dynamic_update_slice(
+                pool, jnp.where(keep[None, :, :, None, None], new, old),
+                idx)
+        return lambda pool: jax.lax.fori_loop(0, n_layers, layer, pool)
+
+    for g, start in enumerate(starts):
+        pool = jax.lax.cond(jnp.any(lanes[g] >= 0),
+                            merge(lanes[g], start), lambda p: p, pool)
+    return pool
+
+
 @jax.named_scope("kv_write")
 def write_pool_blocks(pool, rows, blocks):
     """Dense rows (L, 1, n*bs, H, Dh) -> pool blocks ``blocks`` (n,):
     block i of the rows lands in pool block ``blocks[i]``; sentinel
-    entries (>= NB) are dropped. A loop of whole-block updates (the
-    pool's block axis is minor on the TPU, where a scatter along it
-    copies the pool)."""
+    entries (>= NB) are dropped. A loop of whole-block updates: a
+    scatter along the block axis, the lane axis of the pool's tiles
+    where Dh is not a multiple of 128 (:func:`write_pool_positions`),
+    makes the compiler copy the pool."""
     nb, bs = pool.shape[1], pool.shape[2]
     rows = rows.astype(pool.dtype)
     upd = (pool.shape[0], 1, bs) + pool.shape[3:]

@@ -1156,7 +1156,9 @@ class ServingEngine:
                 if self.telemetry.enabled else {})
         logits, ks, vs = self._dispatch(
             f"chunk_{view['kind']}", fn, self.params, *args, **work)
-        with self._span("engine.kv.splice", cat="kv", rid=req.rid):
+        groups = self.kv.alloc_chunk(slot, h, n_suf)
+        with self._span("engine.kv.splice", cat="kv", rid=req.rid,
+                        groups=groups):
             self.kv.splice_partial(ks, vs, slot, h, n_suf)
         self.prefill_chunk_dispatches += 1
         self.admission_log.append(req.rid)
@@ -1248,7 +1250,9 @@ class ServingEngine:
                 if self.telemetry.enabled else {})
         logits, ks, vs = self._dispatch(
             f"chunk_{view['kind']}", fn, self.params, *args, **work)
-        with self._span("engine.kv.splice", cat="kv", rid=req.rid):
+        groups = self.kv.alloc_chunk(slot, st.done, n_valid)
+        with self._span("engine.kv.splice", cat="kv", rid=req.rid,
+                        groups=groups):
             self.kv.splice_partial(ks, vs, slot, st.done, n_valid)
         self.prefill_chunk_dispatches += 1
         req.prefill_chunks += 1
@@ -1479,6 +1483,10 @@ class ServingEngine:
             "prefix_hit_rate": float(
                 getattr(self.kv, "prefix_hit_rate", 0.0)),
             "prefix_hits": int(getattr(self.kv, "prefix_hits", 0)),
+            # lane groups of pool blocks the paged chunk splices rewrote
+            # (one or two a chunk when the pool hands out neighbours)
+            "chunk_splice_groups": int(
+                getattr(self.kv, "chunk_splice_groups", 0)),
             "prefix_hit_tokens": int(
                 getattr(self.kv, "prefix_hit_tokens", 0)),
             "prefix_lookups": int(getattr(self.kv, "prefix_lookups", 0)),

@@ -154,6 +154,12 @@ class KVCacheManager(Protocol):
         backends allocate exactly the blocks the span touches."""
         ...
 
+    def alloc_chunk(self, slot: int, offset: int, n_valid: int) -> int:
+        """Allocate what :meth:`splice_partial` of the same span will
+        write, and return the lane groups of pool blocks it rewrites (0
+        for a contiguous cache, which has no pool)."""
+        ...
+
     def chunk_view(self, slot: int) -> dict:
         """Device operands for one chunked-prefill dispatch over this
         slot's cached history: ``{"kind": "contiguous", "k", "v",
@@ -469,15 +475,17 @@ def gather_blocks(pool, blocks):
 
 
 @partial(jax.jit, donate_argnums=(0, 1))
-def splice_positions(pool_k, pool_v, rows_k, rows_v, blk, off):
-    """Per-position scatter for chunked prefill: position i of the
-    chunk (L, 1, S, H, Dh) lands in pool block ``blk[i]`` at row
-    ``off[i]`` (sentinel entries — the pad tail — are dropped). No
-    alignment requirement between chunk offsets and the block size: a
-    vlm image prefix can shift every chunk boundary. One compile per
-    chunk shape."""
-    return (MD.write_pool_positions(pool_k, rows_k, blk[None], off[None]),
-            MD.write_pool_positions(pool_v, rows_v, blk[None], off[None]))
+def splice_positions(pool_k, pool_v, rows_k, rows_v, lanes, shift,
+                     n_valid):
+    """Chunked prefill: one chunk's rows (L, 1, S, H, Dh), at chunk
+    position ``shift`` of its first block, land in the pool one lane
+    group of blocks at a time (:func:`~repro.models.model.write_pool_chunk`,
+    ``lanes`` from :meth:`PagedCache.chunk_plan`); the pad tail past
+    ``n_valid`` is dropped. No alignment requirement between chunk
+    offsets and the block size: a vlm image prefix can shift every
+    chunk boundary. One compile per chunk shape."""
+    return (MD.write_pool_chunk(pool_k, rows_k, lanes, shift, n_valid),
+            MD.write_pool_chunk(pool_v, rows_v, lanes, shift, n_valid))
 
 
 def _init_sharded(make, shardings_of):
@@ -531,6 +539,9 @@ class ContiguousCache:
 
     def reserve(self, slot: int, n_prompt: int, budget: int) -> None:
         self._occupied.add(slot)  # capacity is pre-provisioned per slot
+
+    def alloc_chunk(self, slot: int, offset: int, n_valid: int) -> int:
+        return 0
 
     def splice_partial(self, k_rows, v_rows, slot: int, offset: int,
                        n_valid: int) -> None:
@@ -675,6 +686,7 @@ class PagedCache:
         self.prefix_hits = 0           # admissions with a nonzero match
         self.prefix_hit_tokens = 0     # prompt tokens served from cache
         self.prefix_lookup_tokens = 0  # prompt tokens across lookups
+        self.chunk_splice_groups = 0   # lane groups chunk splices wrote
 
     # -- accounting -------------------------------------------------------
     def _need_blocks(self, n_prompt: int, budget: int) -> int:
@@ -814,22 +826,42 @@ class PagedCache:
         admission)."""
         self._reserved[slot] = self._need_blocks(n_prompt, budget)
 
-    def splice_partial(self, k_rows, v_rows, slot: int, offset: int,
-                       n_valid: int) -> None:
+    def _alloc_span(self, slot: int, offset: int, n_valid: int) -> None:
         bs = self.block_size
         for b in range(offset // bs,
                        math.ceil((offset + n_valid) / bs)):
             if self.table[slot, b] == self.num_blocks:
                 self.table[slot, b] = self._alloc_block()
                 self._reserved[slot] = max(0, int(self._reserved[slot]) - 1)
-        s = int(k_rows.shape[2])
-        pos = offset + np.arange(s)
-        blk = np.full(s, self.num_blocks, np.int32)
-        valid = np.arange(s) < n_valid
-        blk[valid] = self.table[slot, pos[valid] // bs]
+
+    def alloc_chunk(self, slot: int, offset: int, n_valid: int) -> int:
+        self._alloc_span(slot, offset, n_valid)
+        return self.chunk_plan(slot, offset, n_valid)[1]
+
+    def chunk_plan(self, slot: int, offset: int, n_valid: int):
+        """Where a chunk's blocks, once allocated, sit in the pool's lane
+        groups (:func:`~repro.models.model.lane_groups`): ``lanes`` (G,
+        GW), the chunk block landing in each block of each group (-1:
+        none), and how many groups the chunk touches."""
+        bs = self.block_size
+        gw, starts = MD.lane_groups(self.num_blocks)
+        end = math.ceil((offset + n_valid) / bs)
+        blocks = self.table[slot, offset // bs:end].astype(np.int64)
+        group = blocks // gw
+        lanes = np.full((len(starts), gw), -1, np.int32)
+        lanes[group, blocks - np.asarray(starts)[group]] = np.arange(
+            blocks.size)
+        return lanes, int(np.unique(group).size)
+
+    def splice_partial(self, k_rows, v_rows, slot: int, offset: int,
+                       n_valid: int) -> None:
+        self._alloc_span(slot, offset, n_valid)
+        lanes, n = self.chunk_plan(slot, offset, n_valid)
+        self.chunk_splice_groups += n
+        i32 = partial(jnp.asarray, dtype=jnp.int32)
         self._pool_k, self._pool_v = splice_positions(
-            self._pool_k, self._pool_v, k_rows, v_rows,
-            jnp.asarray(blk), jnp.asarray(pos % bs, np.int32))
+            self._pool_k, self._pool_v, k_rows, v_rows, i32(lanes),
+            i32(offset % self.block_size), i32(n_valid))
 
     def chunk_view(self, slot: int) -> dict:
         return {"kind": "paged", "k": self._pool_k, "v": self._pool_v,

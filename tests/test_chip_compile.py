@@ -27,6 +27,7 @@ from repro.kernels import flash_attention as FA
 from repro.kernels import quant_gemv as QG
 from repro.kernels import rmsnorm as RN
 from repro.models import model as MD
+from repro.serving import kv_cache as KV
 from repro.serving.engine import CACHE_ARGNUM, build_closures
 from repro.serving.kv_cache import contiguous_kv_bytes
 
@@ -151,3 +152,41 @@ def test_engine_chunk_step_fits_one_chip(one_chip, kv_cache):
     kv_bytes = contiguous_kv_bytes(PHI3, slots, capacity)
     assert mem.peak_memory_in_bytes <= HBM_BYTES
     assert mem.temp_size_in_bytes < kv_bytes // 4
+
+
+def test_chunk_splice_rewrites_the_pool_in_place(one_chip):
+    """The paged chunk splice at phi3's pool (1024 blocks of 16 positions
+    x 32 heads x 96) and a 256-token chunk: both donated pools alias the
+    outputs, temporaries stay under 1 GiB (phi3 serves at 85% of HBM),
+    and the pool keeps the decode program's layout, so neither program
+    relayouts the other's buffer."""
+    slots, capacity = _smoke_sizes()
+    put = functools.partial(jax.tree.map, lambda a: _sds(one_chip, a.shape,
+                                                         a.dtype))
+    cache = put(MD.paged_cache_spec(PHI3, slots, capacity, 16))
+    nb = cache["k"].shape[1]
+    assert nb == 1024
+    i32 = jnp.int32
+    scalar = _sds(one_chip, (), i32)
+    rows = _sds(one_chip, (PHI3.n_layers, 1, 256, PHI3.n_kv_heads,
+                           PHI3.d_head))
+    splice = KV.splice_positions.lower(
+        cache["k"], cache["v"], rows, rows,
+        _sds(one_chip, (nb // 128, 128), i32), scalar, scalar).compile()
+    mem = splice.memory_analysis()
+    pool_bytes = cache["k"].size * cache["k"].dtype.itemsize
+    assert mem.alias_size_in_bytes >= 2 * pool_bytes
+    assert mem.temp_size_in_bytes < 2**30
+
+    params = put(jax.eval_shape(
+        lambda: MD.init_params(jax.random.PRNGKey(0), PHI3)))
+    decode = jax.jit(build_closures(PHI3, capacity)["decode"],
+                     donate_argnums=CACHE_ARGNUM).lower(
+        params, _sds(one_chip, (slots, 1), i32), cache,
+        _sds(one_chip, (slots,), i32),
+        _sds(one_chip, (slots,), jnp.bool_)).compile()
+    pool_layout = decode.input_formats[0][2]["k"].layout
+    assert [f.layout for f in splice.input_formats[0][:2]] == [
+        pool_layout, pool_layout]
+    assert [f.layout for f in splice.output_formats] == [
+        pool_layout, pool_layout]

@@ -68,8 +68,9 @@ def test_chunk_program_and_its_splice_name_their_layers(engines, kv):
     view = eng.kv.chunk_view(0)
     k, v = view["k"], view["v"]
     if kv == "paged":
-        idx = jax.ShapeDtypeStruct((16,), np.int32)
-        text = KV.splice_positions.lower(k, v, rows, rows, idx, idx)
+        lanes = jax.ShapeDtypeStruct(     # one lane group: NB < 128
+            (1, eng.kv.num_blocks), np.int32)
+        text = KV.splice_positions.lower(k, v, rows, rows, lanes, i32, i32)
     else:
         text = KV.splice_chunk.lower(k, v, rows, rows, i32, i32, i32)
     assert "kv_write" in _scopes(text.as_text(debug_info=True))
