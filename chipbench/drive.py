@@ -40,7 +40,9 @@ class Record:
     w1: float
     setup_s: float = 0.0
     trace: dict | None = None   # trace.reduce() of the traced stretch
+    spans: dict | None = None   # spans.reduce() of the traced stretch
     stretch_work: dict | None = None   # kind -> work.Work in the stretch
+    needed: object = None       # families.Needed of the configuration
     peaks: dict | None = None
     chips: int = 1
 

@@ -26,6 +26,9 @@ import jax.numpy as jnp
 import numpy as np
 
 Q_BLOCK = 256          # queries per attention block in the reference
+# the program's layer scopes for this family (``jax.named_scope`` in
+# ``models/model.py``); its needed work is the dense count of ``work.py``
+SCOPES = ("attention", "kv_write", "mlp", "logits", "attn_proj")
 
 
 def dims(c: dict) -> dict:

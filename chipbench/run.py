@@ -4,15 +4,19 @@
         --trace <0|1>
 
 The cell, its configuration file, its traffic mix and its metrics are
-found by name from ``BENCHMARK.json``. The run draws the weights on the
-device from the seed (``chipbench/families/<family>.py``), builds the
-serving engine the configuration names, warms every prompt shape the
-mix can draw, then sends the mix's requests when they are due and steps
-the engine: a ramp, the measured window of ``--seconds``, and a drain.
-With ``--trace 0`` it reports the cell's end-to-end metrics, with
-``--trace 1`` its per-layer metrics from a profiler trace of a steady
-stretch. Then it frees the engine and decides ``correct`` against the
-float32 reference (``chipbench/check.py``).
+found by name from ``BENCHMARK.json``, and what the cell itself sets
+(``chipbench/cells/<cell>.json``, where there is one) is merged over its
+configuration. The run draws the weights on the device from the seed
+(the configuration's family, ``chipbench/families``), builds the
+serving engine the configuration names (or a cluster of one worker per
+chip), warms every prompt shape the mix can draw, then sends the mix's
+requests when they are due and steps the engine: a ramp, the measured
+window of ``--seconds``, and a drain. With ``--trace 0`` it reports the
+cell's end-to-end metrics, with ``--trace 1`` its per-layer metrics from
+a profiler trace of a steady stretch, taken with the program's own
+telemetry on and programs compiled afresh. Then it frees the engine and
+decides ``correct`` against the float32 reference
+(``chipbench/check.py``).
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device`` (and ``breakdown``
@@ -28,7 +32,6 @@ T_START = time.perf_counter()
 
 import argparse  # noqa: E402
 import gc  # noqa: E402
-import importlib  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
@@ -39,6 +42,7 @@ from pathlib import Path  # noqa: E402
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parent
 BENCH = ROOT / "BENCHMARK.json"
+CELLS_DIR = HERE / "cells"
 CACHE_DIR = ROOT / ".jax_cache"
 TRACE_DIR = ROOT / ".chipbench_trace"
 TRACE_S = 10.0          # traced stretch, unless the mix sets trace_s
@@ -50,7 +54,10 @@ COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 def load_cell(name: str, bench_path: Path = BENCH):
     """(cell, configuration, traffic mix, end-to-end names, per-layer
-    names) of workload ``name``."""
+    names) of workload ``name``. The cell's own file, where there is
+    one, may hold ``engine`` (``EngineConfig`` fields merged over the
+    configuration's) and ``cluster`` (``{"n_prefill", "n_decode"}``:
+    serve with a ``ClusterEngine`` of one worker per chip)."""
     from chipbench import loadgen
     bench = json.loads(bench_path.read_text())
     cells = {w["name"]: w for w in bench["workloads"]}
@@ -59,6 +66,12 @@ def load_cell(name: str, bench_path: Path = BENCH):
     cell = cells[name]
     conf = {c["name"]: c for c in bench["configs"]}[cell["config"]]
     c = json.loads((ROOT / conf["file"]).read_text())
+    own = CELLS_DIR / f"{name}.json"
+    if own.is_file():
+        own = json.loads(own.read_text())
+        c["engine"] = {**c["engine"], **own.get("engine", {})}
+        if "cluster" in own:
+            c["cluster"] = own["cluster"]
     mix = loadgen.load_mix(cell["traffic"])
 
     def names(group):
@@ -94,26 +107,48 @@ def warm_lengths(spec: dict) -> list:
     return sorted(out)
 
 
-def warm(eng, mix: dict, vocab: int, max_batch: int) -> int:
+def warm(eng, mix: dict, vocab: int, max_batch: int, plan=None) -> int:
     """Serve one short request per warm length, a batch at a time, so
-    every program the window uses is compiled or loaded. Returns the
-    number of warm requests."""
+    every program the window uses is compiled or loaded. A cluster
+    hands a prompt's KV to one decode worker, each on its own chip, and
+    compiles the handoff's gather and splice for each count of KV blocks,
+    so there the warm lengths are every prompt length of ``plan`` (the
+    run's plan), and each is served once per decode worker: that many
+    prompts of a length go in together and the router, least loaded
+    first, gives each worker one. A handoff's splice into a pool that no
+    decode has returned yet compiles apart from one into a pool a decode
+    returned, as in the window, so a cluster first serves one request
+    on each decode worker. Returns the number of warm requests."""
     import numpy as np
     rng = np.random.default_rng(0)
     lens = warm_lengths(mix["prompt"])
-    for i in range(0, len(lens), max_batch):
-        for n in lens[i:i + max_batch]:
-            eng.submit(rng.integers(0, vocab, n, dtype=np.int32),
-                       max_new_tokens=3)
+    copies = len(getattr(eng, "decode_workers", [eng]))
+    if copies > 1:
+        lens = sorted({len(p) for p in plan.prompts})
+    batches = [lens[i:i + max_batch] for i in range(0, len(lens),
+                                                     max_batch)]
+    if copies > 1:
+        batches.insert(0, lens[:1])
+    for batch in batches:
+        for n in batch:
+            for _ in range(copies):
+                eng.submit(rng.integers(0, vocab, n, dtype=np.int32),
+                           max_new_tokens=3)
         eng.run()
-    return len(lens)
+    return sum(map(len, batches)) * copies
 
 
-def configure_cache() -> None:
+def configure_cache(trace: bool = False) -> None:
     """JAX's persistent compilation cache: where
     ``JAX_COMPILATION_CACHE_DIR`` says, else at a fixed directory in the
-    checkout; every program is kept, however quickly it compiled."""
+    checkout; every program is kept, however quickly it compiled. A
+    traced run compiles its own programs: the cache's key leaves out the
+    operations' names, so a program loaded from it may carry the layer
+    scopes of another tree (``spans.py``)."""
     import jax
+    if trace:
+        jax.config.update("jax_enable_compilation_cache", False)
+        return
     if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
         jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
@@ -126,7 +161,8 @@ def _device_record(devs) -> dict:
         stats = d.memory_stats() or {}
         peaks.append(int(stats.get("peak_bytes_in_use", 0)))
     return {"platform": devs[0].platform, "kind": devs[0].device_kind,
-            "count": len(devs), "memory_peak_bytes": max(peaks)}
+            "count": len(devs), "memory_peak_bytes": max(peaks),
+            "memory_peak_bytes_per_chip": peaks}
 
 
 def run(c: dict, mix: dict, *, seed: int, seconds: float, trace: bool,
@@ -140,7 +176,8 @@ def run(c: dict, mix: dict, *, seed: int, seconds: float, trace: bool,
     ``control``."""
     import jax
 
-    from chipbench import check, drive, loadgen, peaks, system
+    from chipbench import check, drive, families, loadgen, peaks, system
+    from chipbench import spans as SP
     from chipbench import trace as TR
     from chipbench import work as W
 
@@ -151,7 +188,8 @@ def run(c: dict, mix: dict, *, seed: int, seconds: float, trace: bool,
         if devs[0].platform == "tpu":
             raise
         pk = None           # no published peaks for a CPU test run
-    family = importlib.import_module(f"chipbench.families.{c['family']}")
+    family = families.load(c)
+    needed = families.Needed(family, c)
     e = c["engine"]
     compiles = [0]
 
@@ -165,9 +203,13 @@ def run(c: dict, mix: dict, *, seed: int, seconds: float, trace: bool,
     weights = jax.block_until_ready(
         family.make_weights(c, seed, dtype=c["torch_dtype"]))
     marks.append(time.perf_counter())
-    eng = system.make_engine(family.program_params(weights), c, base_arch)
+    eng = system.make_engine(family.program_params(weights), c, base_arch,
+                             telemetry=trace, devices=devs)
     marks.append(time.perf_counter())
-    n_warm = warm(eng, mix, int(c["vocab_size"]), int(e["max_batch"]))
+    plan = loadgen.make_plan(mix, seed, seconds, int(c["vocab_size"]),
+                             int(e["max_seq_len"]))
+    n_warm = warm(eng, mix, int(c["vocab_size"]), int(e["max_batch"]),
+                  plan)
     marks.append(time.perf_counter())
     setup_s = marks[-1] - t_start
     parts = " ".join(f"{n} {b - a:.2f}" for n, a, b in zip(
@@ -177,14 +219,12 @@ def run(c: dict, mix: dict, *, seed: int, seconds: float, trace: bool,
         f"{compiles[0]} programs compiled or loaded)", file=sys.stderr)
     if fault is not None:
         fault(eng)
-    plan = loadgen.make_plan(mix, seed, seconds, int(c["vocab_size"]),
-                             int(e["max_seq_len"]))
     tracer = watch = None
     if trace:
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
         tracer = drive.Tracer(TRACE_DIR,
                               min(float(mix.get("trace_s", TRACE_S)), seconds))
-        watch = system.Watch(eng, c)
+        watch = system.Watch(eng, needed)
     before = compiles[0]
     rec = drive.serve(eng, plan, ramp_s=float(mix["ramp_s"]),
                       seconds=seconds, drain_cap_s=float(mix["drain_cap_s"]),
@@ -193,6 +233,7 @@ def run(c: dict, mix: dict, *, seed: int, seconds: float, trace: bool,
     records = watch.records if watch is not None and watch.enabled else None
     watch = None        # its wrappers hold the engine
     rec.setup_s, rec.peaks, rec.chips = setup_s, pk, len(devs)
+    rec.needed = needed
     device = _device_record(devs)
     finished = {id(r) for r in eng.finished}
     done = [s for s in rec.sent if id(s.req) in finished]
@@ -202,9 +243,11 @@ def run(c: dict, mix: dict, *, seed: int, seconds: float, trace: bool,
 
     breakdown = None
     if trace:
-        red = TR.reduce(TR.extract(TR.find_xplane(TRACE_DIR)))
+        events = SP.extract(TR.find_xplane(TRACE_DIR))
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
-        rec.trace = red
+        red = TR.reduce(events)
+        rec.trace, rec.spans = red, SP.reduce(events, needed.scopes)
+        del events
         device.update(busy_s=red["busy_s"], window_s=red["window_s"])
         if records is not None:
             kinds = {"decode": "decode", "prefill": "prefill",
@@ -217,7 +260,9 @@ def run(c: dict, mix: dict, *, seed: int, seconds: float, trace: bool,
             rec.stretch_work = work
         breakdown = {"device_ops": red["device_ops"][:10],
                      "idle_gaps": red["idle_gaps"][:10]}
-        log(json.dumps({"programs": red["programs"]}), file=sys.stderr)
+        log(json.dumps({"programs": red["programs"],
+                        "dispatch": rec.spans["dispatch"]}),
+            file=sys.stderr)
 
     metrics = {}
     bench = json.loads(BENCH.read_text()) if BENCH.is_file() else None
@@ -274,7 +319,7 @@ def main(argv=None) -> int:
         print(f"chipbench: {err}", file=sys.stderr)
         return 2
     import jax
-    configure_cache()
+    configure_cache(trace=bool(args.trace))
     devs = jax.devices()
     if devs[0].platform != "tpu":
         print(f"chipbench: JAX found no TPU (platform "

@@ -3,36 +3,42 @@ the serving program itself writes into it.
 
 With its telemetry on, the program marks each engine step
 (``engine.step``), each dispatch's enqueue (``engine.dispatch.<kind>``,
-with the work it carries as arguments ``rows``, ``keys`` and
-``new_tokens``), each device-to-host read (``engine.readback``) and its
-other phases as host annotations, and names the layers of its device
-programs with ``jax.named_scope`` (:data:`SCOPES`), which the profiler
-keeps in each device operation's ``op_name`` metadata.
+with the work it carries as integer arguments: ``rows``, ``keys`` and
+``new_tokens`` today), each device-to-host read (``engine.readback``),
+its other phases (``engine.*``, and ``cluster.*`` in a cluster) as host
+annotations, and names the layers of its device programs with
+``jax.named_scope``, which the profiler keeps in each device
+operation's ``op_name`` metadata. Which names are layer scopes is the
+configuration's family's to say (``families.Needed.scopes``).
 
 :func:`extract` keeps from one ``.xplane.pb`` file what this reduction
-reads, as events ``[plane, line, name, start_ns, duration_ns, args]``:
-device operations with their scope path (``args`` ``{"op": path}``, from
-the operation metadata the trace keeps), device programs, and the host
-spans whose names start with ``chipbench.`` or ``engine.`` with their
-integer arguments. A program loaded from JAX's persistent compilation
-cache carries the op names of the tree that compiled it: the cache key
-leaves debug information out, so a program that differs only in its
-scopes is served from an entry compiled without them.
+and ``trace.reduce`` read, as events ``[plane, line, name, start_ns,
+duration_ns, args]``: device operations with their scope path (``args``
+``{"op": path}``, from the operation metadata the trace keeps), device
+programs, and the host spans whose names start with ``chipbench.``,
+``engine.`` or ``cluster.`` with every integer argument the program gave
+them. A program loaded from JAX's persistent compilation cache carries
+the op names of the tree that compiled it: the cache key leaves debug
+information out, so a program that differs only in its scopes is served
+from an entry compiled without them (a traced run compiles its own,
+``run.py``).
 :func:`reduce` computes over the ``chipbench.window`` stretch:
 
 - ``device_s``: device seconds of operations by program kind
-  (``trace.kind_of``) and scope, the innermost of :data:`SCOPES` on the
-  operation's path, else ``unscoped`` (never dropped); control-flow
-  operations are left out, as in ``trace.reduce``;
+  (``trace.kind_of``) and scope, the innermost of the family's scopes
+  on the operation's path, else ``unscoped`` (never dropped);
+  control-flow operations are left out, as in ``trace.reduce``;
 - ``dispatch``: by dispatch kind, the number of dispatches enqueued in
-  the stretch and the sums of their ``rows``, ``keys`` and
-  ``new_tokens``;
+  the stretch (``n``) and the sum of each integer argument of their
+  spans, identifiers (:data:`IDS`) aside;
+- ``host_s``: by host span name, the duration of every span that
+  started in the stretch (seconds);
 - ``steps`` and ``host_self_s``: the ``engine.step`` spans inside the
   stretch and their mean host time outside their ``engine.readback``
   children (the host's own work per step, not its waits on the device).
 
 The metric functions below turn that into shares of a roofline (at the
-peaks of ``peaks.py``, with the work of ``layer_work.py``) and the host's
+peaks of ``peaks.py``, with the family's needed work) and the host's
 self time per step.
 """
 from __future__ import annotations
@@ -40,15 +46,14 @@ from __future__ import annotations
 from collections import defaultdict
 from pathlib import Path
 
-from chipbench import layer_work as LW
 from chipbench import trace as TR
 
-SCOPES = ("attention", "kv_write", "mlp", "logits", "attn_proj")
 UNSCOPED = "unscoped"
 STEP_SPAN = "engine.step"
 READBACK_SPAN = "engine.readback"
 DISPATCH_PREFIX = "engine.dispatch."
-WORK_ARGS = ("rows", "keys", "new_tokens")
+# integer span arguments that name something rather than count work
+IDS = ("rid",)
 TF_OP_STAT = "tf_op"
 
 
@@ -72,9 +77,10 @@ def extract(path) -> list:
                 if host and not e.name.startswith(TR._HOST_PREFIXES):
                     continue
                 args = {}
-                if host:
+                if host:        # the profiler's own stats start with "_"
                     args = {k: v for k, v in e.stats
-                            if k in WORK_ARGS or k in ("rid", "step_num")}
+                            if isinstance(v, int) and not isinstance(v, bool)
+                            and not k.startswith("_")}
                 elif (plane.name, e.name) in paths:
                     args = {"op": paths[(plane.name, e.name)]}
                 out.append([plane.name, line.name if dev else "host",
@@ -157,11 +163,11 @@ def op_names(data) -> dict:
     return out
 
 
-def scope_of(op_path: str | None) -> str:
-    """The innermost layer scope on an operation's ``op_name`` path."""
+def scope_of(op_path: str | None, scopes) -> str:
+    """The innermost of ``scopes`` on an operation's ``op_name`` path."""
     for part in reversed((op_path or "").split("/")):
         part = part.split(":")[0]         # a device's "name:type" form
-        if part in SCOPES:
+        if part in scopes:
             return part
     return UNSCOPED
 
@@ -174,8 +180,9 @@ def _window(events):
     return win[0]
 
 
-def reduce(events: list) -> dict:
-    """The stretch's per-layer numbers (see the module docstring).
+def reduce(events: list, scopes=()) -> dict:
+    """The stretch's per-layer numbers (see the module docstring), with
+    device time by the layer ``scopes`` of the configuration's family.
     Raises ``ValueError`` when the stretch or the device is missing."""
     lo, hi = _window(events)
     by_plane = defaultdict(lambda: {"ops": [], "mods": []})
@@ -205,18 +212,22 @@ def reduce(events: list) -> dict:
                     if j < len(mods) and mods[j][0] <= s else "?")
             if TR.op_name(name).split(".")[0] in TR._CONTAINERS:
                 continue
-            device[TR.kind_of(prog)][scope_of(path)] += (ce - cs) * 1e-9
+            device[TR.kind_of(prog)][scope_of(path, scopes)] += \
+                (ce - cs) * 1e-9
     n = len(chips)
-    dispatch = defaultdict(lambda: dict.fromkeys(("n",) + WORK_ARGS, 0))
+    dispatch = defaultdict(lambda: defaultdict(int))
+    host_s = defaultdict(list)
     steps, readbacks = [], []
     for s, e, name, args in host:
-        if not lo <= s < hi:
+        if not lo <= s < hi or name == TR.WINDOW_SPAN:
             continue
+        host_s[name].append((e - s) * 1e-9)
         if name.startswith(DISPATCH_PREFIX):
             d = dispatch[name[len(DISPATCH_PREFIX):]]
             d["n"] += 1
-            for k in WORK_ARGS:
-                d[k] += int(args.get(k, 0))
+            for k, v in args.items():
+                if k not in IDS:
+                    d[k] += int(v)
         elif name == STEP_SPAN and e <= hi:
             steps.append((s, e))
         elif name == READBACK_SPAN:
@@ -230,6 +241,7 @@ def reduce(events: list) -> dict:
         "device_s": {k: {sc: v / n for sc, v in d.items()}
                      for k, d in device.items()},
         "dispatch": {k: dict(v) for k, v in dispatch.items()},
+        "host_s": dict(host_s),
         "steps": len(steps),
         "host_self_s": sum(self_s) / len(self_s) if self_s else None,
     }
@@ -242,30 +254,30 @@ def unscoped_share(red: dict, kind: str) -> float | None:
     return 100.0 * d.get(UNSCOPED, 0.0) / total if total > 0 else None
 
 
-def decode_attention_roofline(red: dict, shapes, peaks: dict):
-    """Least time of the decode dispatches' attention
-    (``layer_work.decode_attention`` of their summed ``keys`` and
-    ``rows``) over the device time of ``attention``-scoped operations in
-    decode programs, in percent."""
+def decode_attention_roofline(red: dict, needed, peaks: dict):
+    """Least time of the decode dispatches' attention (the family's
+    ``decode_attention_work`` of their summed ``keys`` and ``rows``)
+    over the device time of ``attention``-scoped operations in decode
+    programs, in percent."""
     d = red["dispatch"].get("decode")
     t = red["device_s"].get("decode", {}).get("attention", 0.0)
-    if not d or t <= 0:
+    if not d or t <= 0 or "keys" not in d:
         return None
-    w = LW.decode_attention(shapes, d["keys"], d["rows"])
+    w = needed.decode_attention_work(d["keys"], d["rows"])
     return 100.0 * w.least_seconds(peaks["bf16_flops"],
                                    peaks["hbm_bytes_s"]) / t
 
 
-def kv_write_roofline(red: dict, shapes, peaks: dict):
+def kv_write_roofline(red: dict, needed, peaks: dict):
     """Least time of writing every dispatch's new keys and values once
-    (``layer_work.kv_write`` of the summed ``new_tokens``) over the
-    device time of ``kv_write``-scoped operations in all programs, in
-    percent."""
-    n = sum(d["new_tokens"] for d in red["dispatch"].values())
+    (the family's ``kv_write_work`` of the summed ``new_tokens``) over
+    the device time of ``kv_write``-scoped operations in all programs,
+    in percent."""
+    n = sum(d.get("new_tokens", 0) for d in red["dispatch"].values())
     t = sum(v.get("kv_write", 0.0) for v in red["device_s"].values())
     if n <= 0 or t <= 0:
         return None
-    w = LW.kv_write(shapes, n)
+    w = needed.kv_write_work(n)
     return 100.0 * w.least_seconds(peaks["bf16_flops"],
                                    peaks["hbm_bytes_s"]) / t
 

@@ -20,7 +20,6 @@ from __future__ import annotations
 
 import argparse
 import gc
-import importlib
 import json
 import sys
 
@@ -35,7 +34,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=1)
     args = ap.parse_args(argv)
 
-    from chipbench import drive, loadgen, run, system
+    from chipbench import drive, families, loadgen, run, system
     system.import_program()
     cell, c, mix, _, _ = run.load_cell(args.workload)
     if mix["loop"] != "open":
@@ -47,7 +46,7 @@ def main(argv=None) -> int:
     if jax.devices()[0].platform != "tpu":
         print("chipbench.sweep: JAX found no TPU", file=sys.stderr)
         return 2
-    family = importlib.import_module(f"chipbench.families.{c['family']}")
+    family = families.load(c)
     weights = family.make_weights(c, args.seed, dtype=c["torch_dtype"])
     e = c["engine"]
     mean_out = float(np.mean(loadgen.lengths(mix["output"], 4096,

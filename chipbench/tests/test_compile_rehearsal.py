@@ -1,10 +1,14 @@
 """Compile rehearsal of each cell for one TPU v5e, with no chip attached:
 the engine's decode program and its largest prefill program, at the
 configuration's published widths and the cell's slots and capacity,
-compiled for a described ``v5e:2x2`` topology. The compiler refuses
-here what the chip would refuse, and ``memory_analysis()`` says whether
-the program, with the resident weights and KV cache among its operands,
-fits one chip's HBM.
+compiled for a described ``v5e:2x2`` topology. A cluster cell serves
+with one worker on each chip, each the same engine, so the same two
+programs are each worker's; beside them the KV handoff's largest
+programs: the gather of a prompt's blocks on the prefill chip and their
+splice into a decode chip's pool. The compiler refuses here what the
+chip would refuse, and ``memory_analysis()`` says whether the program,
+with the resident weights and KV cache among its operands or beside
+them, fits one chip's HBM.
 
 The topology is described inside a module fixture, never at import
 time: only one process may load the TPU library, and the test workers
@@ -21,8 +25,9 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from chipbench import loadgen, system
+from chipbench import run, system
 from chipbench.families import dense
+from chipbench.tests import tiny
 
 ROOT = Path(__file__).resolve().parents[2]
 HBM_BYTES = int(15.75 * 2**30)   # what XLA lets one v5e program use
@@ -30,12 +35,15 @@ HBM_BYTES = int(15.75 * 2**30)   # what XLA lets one v5e program use
 
 def _cells():
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
-    files = {c["name"]: c["file"] for c in bench["configs"]}
-    return [(w["name"], files[w["config"]], w["traffic"])
-            for w in bench["workloads"] if w["chips"] == 1]
+    return [w["name"] for w in bench["workloads"]]
 
 
-CELLS = _cells()
+CELLS = _cells() + [tiny.DISAGG["name"]]
+
+
+@pytest.fixture(scope="module")
+def bench(tmp_path_factory):
+    return tiny.bench_with(tmp_path_factory.mktemp("bench"), tiny.DISAGG)
 
 
 @pytest.fixture(scope="module")
@@ -61,9 +69,10 @@ def one_chip():
 def _programs(c: dict, mix: dict, sh):
     """(name, jitted closure, operand shapes, bytes resident beside the
     operands) of the cell's decode program and its largest prefill
-    program."""
+    program, and in a cluster its largest KV handoff programs."""
     system.import_program()
     from repro.models import model as MD
+    from repro.serving import kv_cache as KV
     from repro.serving.engine import CACHE_ARGNUM, build_closures
 
     arch = system.arch_config(c)
@@ -100,21 +109,39 @@ def _programs(c: dict, mix: dict, sh):
         pre = ("chunk_paged", jax.jit(closures["chunk_paged"]),
                (params, batch, cache["k"], cache["v"], table, i32, i32), 0)
     else:
-        n = int(mix["prompt"]["max"])   # the longest prompt of the mix
+        # the longest prompt of the mix, padded to its bucket where the
+        # engine buckets (a power of two, at most the prompt capacity)
+        n = int(mix["prompt"]["max"])
+        if arch.sliding_window is None:
+            n = min(1 << (n - 1).bit_length(), C - 1)
         batch = {"tokens": jax.ShapeDtypeStruct((1, n), jnp.int32,
                                                 sharding=sh)}
         kv = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(cache))
         pre = ("prefill", jax.jit(closures["prefill"]),
                (params, batch, i32, i32), kv)
-    return [decode, pre]
+    out = [decode, pre]
+    if c.get("cluster"):
+        bs = int(e["kv_block_size"])
+        nb = -(-int(mix["prompt"]["max"]) // bs)   # blocks of a handoff
+        pool = cache["k"]
+        rows = jax.ShapeDtypeStruct(
+            (pool.shape[0], 1, nb * bs) + pool.shape[3:], pool.dtype,
+            sharding=sh)
+        idx = jax.ShapeDtypeStruct((nb,), jnp.int32, sharding=sh)
+        weights = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree.leaves(params))
+        half = pool.size * pool.dtype.itemsize
+        out += [("gather_blocks", KV.gather_blocks, (pool, idx),
+                 weights + half),
+                ("splice_blocks", KV.splice_blocks,
+                 (pool, cache["v"], rows, rows, idx), weights)]
+    return out
 
 
-@pytest.mark.parametrize("cell,path,mix", CELLS,
-                         ids=[cell[0] for cell in CELLS])
-def test_cell_programs_fit_one_chip(one_chip, cell, path, mix):
-    c = json.loads((ROOT / path).read_text())
-    for name, fn, args, resident in _programs(c, loadgen.load_mix(mix),
-                                              one_chip):
+@pytest.mark.parametrize("cell", CELLS)
+def test_cell_programs_fit_one_chip(one_chip, bench, cell):
+    _, c, mix, _, _ = run.load_cell(cell, bench)
+    for name, fn, args, resident in _programs(c, mix, one_chip):
         m = fn.lower(*args).compile().memory_analysis()
         total = (m.argument_size_in_bytes + m.output_size_in_bytes
                  - m.alias_size_in_bytes + m.temp_size_in_bytes + resident)

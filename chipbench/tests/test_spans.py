@@ -12,12 +12,15 @@ from types import SimpleNamespace
 
 import pytest
 
-from chipbench import drive
+from chipbench import drive, families
 from chipbench import layer_work as LW
 from chipbench import run
 from chipbench import spans as SP
 from chipbench import trace as TR
 from chipbench import work as W
+from chipbench.families import dense
+
+SCOPES = dense.SCOPES
 
 D = "/device:TPU:0"
 
@@ -73,23 +76,29 @@ def _synthetic():
     ]
 
 
-SHAPES = W.Shapes(d=8, layers=2, heads=2, kv_heads=1, head_dim=4, ff=16,
-                  vocab=10)
+TINY = {"hidden_size": 8, "num_hidden_layers": 2, "num_attention_heads": 2,
+        "num_key_value_heads": 1, "head_dim": 4, "intermediate_size": 16,
+        "vocab_size": 10, "torch_dtype": "bfloat16", "family": "dense"}
+NEEDED = families.Needed(dense, TINY)
+SHAPES = NEEDED.shapes
 PEAKS = {"bf16_flops": 1e12, "hbm_bytes_s": 1e10}
 
 
 def test_scope_is_the_innermost_layer_on_the_path():
-    assert SP.scope_of("jit(decode)/while/body/attention/dot") == \
+    assert SP.scope_of("jit(decode)/while/body/attention/dot", SCOPES) == \
         "attention"
-    assert SP.scope_of("jit(f)/mlp/attention/x") == "attention"
-    assert SP.scope_of("jit(decode)/jit(decode_attention)/x") == \
+    assert SP.scope_of("jit(f)/mlp/attention/x", SCOPES) == "attention"
+    assert SP.scope_of("jit(decode)/jit(decode_attention)/x", SCOPES) == \
         SP.UNSCOPED
-    assert SP.scope_of("jit(f)/mlp/attention:") == "attention"
-    assert SP.scope_of("") == SP.scope_of(None) == SP.UNSCOPED
+    assert SP.scope_of("jit(f)/mlp/attention:", SCOPES) == "attention"
+    assert SP.scope_of("", SCOPES) == SP.scope_of(None, SCOPES) == \
+        SP.UNSCOPED
+    # a family that names no scopes leaves every operation unscoped
+    assert SP.scope_of("jit(f)/mlp/attention/x", ()) == SP.UNSCOPED
 
 
 def test_reduce_by_kind_and_scope_dispatch_work_and_host_self_time():
-    red = SP.reduce(_synthetic())
+    red = SP.reduce(_synthetic(), SCOPES)
     assert red["window_s"] == pytest.approx(2000e-9)
     dev = red["device_s"]
     # the while loop's own span is left out; copy.3 has no scope
@@ -103,6 +112,12 @@ def test_reduce_by_kind_and_scope_dispatch_work_and_host_self_time():
     assert red["dispatch"] == {
         "decode": {"n": 2, "rows": 4, "keys": 62, "new_tokens": 4},
         "prefill": {"n": 1, "rows": 1, "keys": 10, "new_tokens": 4}}
+    # every span that started in the stretch, by name
+    assert red["host_s"]["engine.step"] == pytest.approx(
+        [800e-9, 900e-9, 100e-9])
+    assert red["host_s"]["engine.readback"] == pytest.approx(
+        [200e-9, 300e-9])
+    assert TR.WINDOW_SPAN not in red["host_s"]
     # two whole steps: (800 - 200) and (900 - 300) ns outside readbacks
     assert red["steps"] == 2
     assert red["host_self_s"] == pytest.approx(600e-9)
@@ -111,15 +126,15 @@ def test_reduce_by_kind_and_scope_dispatch_work_and_host_self_time():
 
 
 def test_metrics_from_the_reduction_by_hand():
-    red = SP.reduce(_synthetic())
+    red = SP.reduce(_synthetic(), SCOPES)
     # decode attention: 62 keys over 4 rows, 32 bytes of KV a position
     # (2 layers x K and V x 1 head x 4 x 2 bytes): 58 x 32 = 1856 bytes
     # at 1e10 B/s = 185.6 ns (the 3968 operations take 3.968 ns), over
     # 220 ns of attention in decode programs
-    assert SP.decode_attention_roofline(red, SHAPES, PEAKS) == \
+    assert SP.decode_attention_roofline(red, NEEDED, PEAKS) == \
         pytest.approx(100 * 185.6 / 220)
     # 8 positions written x 32 bytes = 25.6 ns over 80 + 50 ns
-    assert SP.kv_write_roofline(red, SHAPES, PEAKS) == pytest.approx(
+    assert SP.kv_write_roofline(red, NEEDED, PEAKS) == pytest.approx(
         100 * 25.6 / 130)
     assert SP.host_self_ms(red) == pytest.approx(600e-6)
 
@@ -129,12 +144,12 @@ def test_metrics_report_nothing_without_spans_or_scopes():
     metrics find nothing to read and say so, and raise nothing."""
     bare = [e[:5] + [{}] for e in _synthetic()
             if e[1] != "host" or e[2] == TR.WINDOW_SPAN]
-    red = SP.reduce(bare)
+    red = SP.reduce(bare, SCOPES)
     assert red["dispatch"] == {} and red["steps"] == 0
     assert red["device_s"]["decode"] == pytest.approx(
         {"unscoped": 400e-9})
-    assert SP.decode_attention_roofline(red, SHAPES, PEAKS) is None
-    assert SP.kv_write_roofline(red, SHAPES, PEAKS) is None
+    assert SP.decode_attention_roofline(red, NEEDED, PEAKS) is None
+    assert SP.kv_write_roofline(red, NEEDED, PEAKS) is None
     assert SP.host_self_ms(red) is None
 
 
@@ -237,7 +252,8 @@ def test_op_names_of_a_recorded_v5e_trace():
     each operation's ``op_name`` (as ``path:``) in its metadata."""
     path = DATA / "scopes.v5e.xplane.pb"
     names = SP.op_names(path.read_bytes())
-    assert {SP.scope_of(p) for p in names.values()} == {"attention"}
+    assert {SP.scope_of(p, SCOPES) for p in names.values()} == \
+        {"attention"}
     ops = [e for e in SP.extract(path) if e[1] == "XLA Ops"]
     assert len(ops) == 3
     assert all(e[5]["op"] == "jit(layer)/attention/dot_general:"
@@ -278,7 +294,8 @@ RECORDED_SPANS = {
 @pytest.mark.parametrize("cell", sorted(RECORDED_SPANS))
 def test_recorded_v5e_slice_with_program_spans(cell):
     want = RECORDED_SPANS[cell]
-    red = SP.reduce(TR.load_events(DATA / f"{cell}.v5e.spans.json.gz"))
+    events = TR.load_events(DATA / f"{cell}.v5e.spans.json.gz")
+    red = SP.reduce(events, SCOPES)
     assert red["window_s"] == pytest.approx(want["window_s"])
     assert red["dispatch"] == want["dispatch"]
     assert red["steps"] == len(want["self_ns"])
@@ -287,11 +304,24 @@ def test_recorded_v5e_slice_with_program_spans(cell):
     assert red["device_s"]["decode"] == pytest.approx(want["decode"])
     cfg = json.loads((run.HERE / "configs" / f"{want['config']}.json")
                      .read_text())
-    shapes, peaks = W.Shapes.from_config(cfg), {
+    needed, peaks = families.Needed(families.load(cfg), cfg), {
         "bf16_flops": 197e12, "hbm_bytes_s": 819e9}
-    assert SP.decode_attention_roofline(red, shapes, peaks) == \
+    assert SP.decode_attention_roofline(red, needed, peaks) == \
         pytest.approx(want["attention_roofline"])
-    assert SP.kv_write_roofline(red, shapes, peaks) == \
+    assert SP.kv_write_roofline(red, needed, peaks) == \
         pytest.approx(want["kv_write_roofline"])
     share = 100 * want["decode"]["unscoped"] / sum(want["decode"].values())
     assert SP.unscoped_share(red, "decode") == pytest.approx(share)
+    # the metric files read the same from a traced run's record, and the
+    # busy union reads the six-field events as it read five
+    rec = drive.Record(sent=[], steps=[], w0=0.0, w1=1.0, spans=red,
+                       needed=needed, peaks=peaks,
+                       trace=TR.reduce(events))
+    assert rec.trace == TR.reduce([e[:5] for e in events])
+    assert run.metric_reader("decode_attention_roofline")(rec) == \
+        pytest.approx(want["attention_roofline"])
+    assert run.metric_reader("kv_write_roofline")(rec) == \
+        pytest.approx(want["kv_write_roofline"])
+    assert run.metric_reader("host_self_ms")(rec) == pytest.approx(
+        sum(want["self_ns"]) / len(want["self_ns"]) * 1e-6)
+    assert run.metric_reader("kv_handoff_p95_ms")(rec) is None

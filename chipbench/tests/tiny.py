@@ -2,7 +2,16 @@
 shapes through the same harness, engine and reference."""
 from __future__ import annotations
 
-from chipbench import system
+import json
+from pathlib import Path
+
+from chipbench import run, system
+
+# the cluster cell whose cell file and traffic mix are in the harness but
+# which is not among the benchmark's workloads: it has not run sound on
+# four chips (PERF.md, Open questions)
+DISAGG = {"name": "phi3-disagg-4chip", "config": "phi3-mini-3.8b",
+          "traffic": "disagg", "chips": 4}
 
 
 def config(kind: str = "paged", dtype: str = "float32", window=None,
@@ -22,6 +31,16 @@ def config(kind: str = "paged", dtype: str = "float32", window=None,
             "torch_dtype": dtype, "family": "dense",
             "registry_arch": "phi3-mini-3.8b", "engine": engine,
             "check": {"max_logit_gap": 0.05, "sample_requests": 3}}
+
+
+def cluster_config(dtype: str = "float32") -> dict:
+    """The paged tiny cell served as a cluster of one prefill and three
+    decode workers, one device each (the cluster takes the blocking
+    scheduler)."""
+    c = config(kind="paged", dtype=dtype)
+    c["engine"]["scheduler"] = "blocking"
+    c["cluster"] = {"n_prefill": 1, "n_decode": 3}
+    return c
 
 
 def base_arch(c: dict):
@@ -44,3 +63,13 @@ def mix(loop: str = "open") -> dict:
     else:
         m.update(loop="closed", clients=4, residual_start=True)
     return m
+
+
+def bench_with(directory: Path, cell: dict) -> Path:
+    """A copy of ``BENCHMARK.json`` in ``directory`` with ``cell`` among
+    its workloads."""
+    bench = json.loads(run.BENCH.read_text())
+    bench["workloads"].append(cell)
+    path = directory / "BENCHMARK.json"
+    path.write_text(json.dumps(bench))
+    return path

@@ -4,12 +4,13 @@ per-layer metrics read.
 A traced run records one steady stretch, marked on the host by the
 annotation ``chipbench.window`` and started and stopped between engine
 steps (every step ends by reading its sampled tokens back, so no device
-work of a step outside the stretch falls in it). :func:`extract` keeps
+work of a step outside the stretch falls in it). ``spans.extract`` keeps
 what the reduction needs from the ``.xplane.pb`` file, as plain events
-``[plane, line, name, start_ns, duration_ns]``: device operations and
-programs (lines ``XLA Ops`` and ``XLA Modules`` of each ``/device:``
-plane) and the host spans the benchmark writes (names starting with
-``chipbench.`` or ``engine.``). :func:`reduce` computes from them:
+``[plane, line, name, start_ns, duration_ns, ...]``: device operations
+and programs (lines ``XLA Ops`` and ``XLA Modules`` of each ``/device:``
+plane) and the host spans of the benchmark and the program (names
+starting with ``chipbench.``, ``engine.`` or ``cluster.``); what follows
+the duration is not read here. :func:`reduce` computes from them:
 
 - ``busy_s``: the union of device operation intervals in the stretch,
   averaged over the chips; ``window_s``: the stretch's length;
@@ -35,7 +36,7 @@ from collections import defaultdict
 from pathlib import Path
 
 WINDOW_SPAN = "chipbench.window"
-_HOST_PREFIXES = ("chipbench.", "engine.")
+_HOST_PREFIXES = ("chipbench.", "engine.", "cluster.")
 _DEVICE_LINES = ("XLA Ops", "XLA Modules")
 _CONTAINERS = ("while", "conditional", "call")
 
@@ -66,28 +67,6 @@ def kind_of(program: str) -> str:
     if program in KV_PROGRAMS:
         return "kv_update"
     return "unattributed"
-
-
-def extract(path) -> list:
-    """Events of one ``.xplane.pb`` file that the reduction reads."""
-    import jax
-    pd = jax.profiler.ProfileData.from_file(str(path))
-    out = []
-    for plane in pd.planes:
-        dev = plane.name.startswith("/device:")
-        host = plane.name.startswith("/host:CPU")
-        if not (dev or host):
-            continue
-        for line in plane.lines:
-            if dev and line.name not in _DEVICE_LINES:
-                continue
-            for e in line.events:
-                if host and not e.name.startswith(_HOST_PREFIXES):
-                    continue
-                out.append([plane.name, line.name if dev else "host",
-                            e.name, float(e.start_ns),
-                            float(e.duration_ns)])
-    return out
 
 
 def find_xplane(directory) -> Path:
@@ -122,7 +101,7 @@ def reduce(events: list) -> dict:
         raise ValueError(f"no {WINDOW_SPAN!r} span in the trace")
     lo, hi = win[0]
     by_plane = defaultdict(lambda: {"ops": [], "mods": []})
-    for plane, line, name, s, d in events:
+    for plane, line, name, s, d, *_ in events:
         if line == "XLA Ops":
             by_plane[plane]["ops"].append((s, s + d, name))
         elif line == "XLA Modules":
