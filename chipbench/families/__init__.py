@@ -24,6 +24,16 @@ operation has a layer scope:
 Each returns a ``work.Work``. ``args`` holds the integer arguments of
 the dispatch's own span (``engine.dispatch.<kind>``), whatever the
 program puts there: empty where the program's telemetry is off.
+
+It may also define how ``system.arch_config`` checks that the program
+runs what the configuration states; where it defines neither, the dense
+decoder's checks of ``arch_check.py`` hold:
+
+- ``WIDTHS``: configuration key -> the program's ``ArchConfig`` field,
+  each of which must equal the key's number (a family whose published
+  key means another field than the dense one maps it here);
+- ``check_arch(c, arch)``: raises ``ValueError`` where the program's
+  ``arch`` would run something else than ``c`` states.
 """
 from __future__ import annotations
 

@@ -22,14 +22,9 @@ from pathlib import Path
 
 import numpy as np
 
-ROOT = Path(__file__).resolve().parents[1]
+from chipbench import arch_check, families
 
-# configuration key -> the program's ArchConfig field; each must agree
-_WIDTHS = {"hidden_size": "d_model", "num_hidden_layers": "n_layers",
-           "num_attention_heads": "n_heads",
-           "num_key_value_heads": "n_kv_heads",
-           "intermediate_size": "d_ff", "vocab_size": "vocab_size",
-           "rope_theta": "rope_theta"}
+ROOT = Path(__file__).resolve().parents[1]
 
 
 def import_program():
@@ -45,35 +40,18 @@ def import_program():
 
 def arch_config(c: dict, base=None):
     """The program's ``ArchConfig`` for configuration ``c`` (its
-    registry entry, or ``base``), checked to run the widths, layers and
-    window the file states; only the RMSNorm epsilon, which the program
-    hard-codes, is not compared. Raises ``ValueError`` where the program
-    would run something else."""
+    registry entry, or ``base``), checked by the configuration's family
+    to run the widths, layers and window the file states: its ``WIDTHS``
+    and ``check_arch``, each ``arch_check``'s where the family defines
+    none. Raises ``ValueError`` where the program would run something
+    else."""
     import_program()
     from repro.configs import registry
+    family = families.load(c)
     arch = base or registry.get_config(c["registry_arch"])
-    for key, field in _WIDTHS.items():
-        if float(getattr(arch, field)) != float(c[key]):
-            raise ValueError(f"{c['registry_arch']}: {key}={c[key]} but "
-                             f"the program runs {field}="
-                             f"{getattr(arch, field)}")
-    dh = int(c.get("head_dim") or c["hidden_size"] //
-             c["num_attention_heads"])
-    if arch.d_head != dh or arch.tie_embeddings != c["tie_word_embeddings"]:
-        raise ValueError("head size or tied embeddings differ from the "
-                         "program")
-    if (arch.activation != "swiglu" or arch.norm != "rmsnorm"
-            or arch.qkv_bias or c.get("attention_bias")):
-        raise ValueError("the dense family expects SwiGLU, RMSNorm and no "
-                         "attention bias")
-    win, cap = c.get("sliding_window"), int(c["engine"]["max_seq_len"])
-    if arch.sliding_window != win:
-        # a window no position of this engine can reach masks nothing:
-        # the program may run the layer without one
-        if not (arch.sliding_window is None and win is not None
-                and win >= cap - 1):
-            raise ValueError(f"sliding_window={win} but the program runs "
-                             f"{arch.sliding_window}")
+    arch_check.check_widths(c, arch, getattr(family, "WIDTHS",
+                                             arch_check.WIDTHS))
+    getattr(family, "check_arch", arch_check.check_arch)(c, arch)
     return arch.replace(dtype=c["torch_dtype"])
 
 

@@ -25,8 +25,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from chipbench import run, system
-from chipbench.families import dense
+from chipbench import families, run, system
 from chipbench.tests import tiny
 
 ROOT = Path(__file__).resolve().parents[2]
@@ -66,16 +65,18 @@ def one_chip():
     jax.config.update("jax_enable_compilation_cache", was)
 
 
-def _programs(c: dict, mix: dict, sh):
+def _programs(c: dict, mix: dict, sh, base=None):
     """(name, jitted closure, operand shapes, bytes resident beside the
     operands) of the cell's decode program and its largest prefill
-    program, and in a cluster its largest KV handoff programs."""
+    program, and in a cluster its largest KV handoff programs. The
+    parameters are laid out by the configuration's family; ``base`` is
+    ``system.arch_config``'s."""
     system.import_program()
     from repro.models import model as MD
     from repro.serving import kv_cache as KV
     from repro.serving.engine import CACHE_ARGNUM, build_closures
 
-    arch = system.arch_config(c)
+    arch = system.arch_config(c, base)
     e = c["engine"]
     B, C = int(e["max_batch"]), int(e["max_seq_len"])
     closures = build_closures(arch, C)
@@ -83,8 +84,9 @@ def _programs(c: dict, mix: dict, sh):
     def sds(x):
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh)
 
+    family = families.load(c)
     params = jax.tree.map(sds, jax.eval_shape(
-        lambda: dense.program_params(dense.make_weights(
+        lambda: family.program_params(family.make_weights(
             c, 0, dtype=c["torch_dtype"]))))
     i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=sh)
     toks = jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=sh)
